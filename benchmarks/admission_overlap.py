@@ -42,6 +42,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import dump_json, emit, record_run
 
 SLOTS = 3
@@ -106,6 +108,7 @@ def serve_once(admit_chunks: int, long_prompt: int, chunk: int, seed: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the results to this BENCH_*.json path")

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import dump_json, emit, record_run, timeit
 
 SLOTS = 4
@@ -58,6 +60,7 @@ def bench(prefetch: bool, rank_votes: bool = True):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the results to this BENCH_*.json path")

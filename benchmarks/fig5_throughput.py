@@ -17,6 +17,7 @@ import argparse
 from repro.core import TraceConfig, synthetic_trace
 from repro.core.costmodel import PAPER_TIMINGS
 from repro.core.simulator import best_cache_config, simulate
+from repro.launch.compile_cache import enable_compile_cache
 from .common import check, emit
 
 
@@ -57,6 +58,7 @@ PAPER_SPEEDUP_FIDDLER = {"mixtral-8x7b": 1.6, "phi35-moe": 4.3}
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--live", action="store_true",
                     help="also run the live batched-scheduler scaling probe")

@@ -16,6 +16,7 @@ from repro.core import (NumpyCache, TraceConfig, random_policy_hit_probs,
                         synthetic_trace)
 from repro.core.costmodel import PAPER_TIMINGS
 from repro.core.simulator import best_cache_config
+from repro.launch.compile_cache import enable_compile_cache
 from .common import emit
 
 TRACES = {
@@ -95,6 +96,7 @@ def prefetch_uplift_sim() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--live", action="store_true",
                     help="capture router trace from a live reduced model")

@@ -30,6 +30,7 @@ import numpy as np
 from repro.core.costmodel import cpu_expert_ms, fetch_expert_ms, \
     gpu_expert_ms
 from repro.hostexec import HostDispatchPolicy
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import dump_json, emit, record_run, timeit
 
@@ -93,6 +94,7 @@ def miss_handling_ms(stats, policy: HostDispatchPolicy):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the results to this BENCH_*.json path")

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.core.costmodel import TPU_HBM_BW, TPU_PEAK_FLOPS_BF16
 from repro.kernels.moe_gmm import grouped_matmul, moe_ffn
 from repro.kernels.decode_attention import decode_attention
+from repro.launch.compile_cache import enable_compile_cache
 from .common import emit, timeit
 
 
@@ -45,6 +46,7 @@ def bench_cache_access() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,

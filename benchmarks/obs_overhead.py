@@ -25,6 +25,7 @@ import argparse
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import TraceRecorder, chrome_trace, validate_chrome_trace
 from repro.obs.export import lifecycle_coverage
 
@@ -42,6 +43,7 @@ def serve(recorder=None):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the results to this BENCH_*.json path")

@@ -31,6 +31,8 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import dump_json, emit, record_run
 
 SLOTS = 4
@@ -112,6 +114,7 @@ def ttft_probe():
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write the results to this BENCH_*.json path")

@@ -10,8 +10,11 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args()

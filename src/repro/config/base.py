@@ -245,10 +245,14 @@ class RuntimeConfig:
     elastic: bool = True
 
 
+def _period(cfg: ModelConfig) -> int:
+    return max(len(cfg.layer_pattern), len(cfg.window_pattern) or 1,
+               cfg.moe_every)
+
+
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests."""
-    period = max(len(cfg.layer_pattern), len(cfg.window_pattern) or 1,
-                 cfg.moe_every)
+    period = _period(cfg)
     changes = dict(
         num_layers=min(cfg.num_layers, 2 * period),
         d_model=128,
@@ -272,3 +276,15 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         changes["window_pattern"] = tuple(64 if w > 0 else -1 for w in cfg.window_pattern)
     changes.update(overrides)
     return dataclasses.replace(cfg, **changes)
+
+
+def with_layers(cfg: ModelConfig, num_layers: int) -> ModelConfig:
+    """The published config cut in depth only: every width, the vocabulary
+    and the expert count stay. ``num_layers`` must be a whole number of
+    layer periods, so every layer kind of the stack is still served."""
+    period = _period(cfg)
+    if not 1 <= num_layers <= cfg.num_layers or num_layers % period:
+        raise ValueError(
+            f"{cfg.name}: num_layers must be a multiple of its layer period "
+            f"{period} in [1, {cfg.num_layers}], got {num_layers}")
+    return dataclasses.replace(cfg, num_layers=num_layers)

@@ -240,19 +240,24 @@ def probe(tiers: ExpertTiers, layer: jax.Array, top_i: jax.Array,
 def _gather_group_weights(tiers: ExpertTiers, layer, pr: ProbeResult,
                           ccfg: CacheConfig):
     """Gather each unique expert's weights once — resident experts from the
-    slot buffer (fast tier), others from the host table (slow tier)."""
-    resident, way = pr.resident, pr.res_way
-    slots = cache_lib.slot_id(layer, jnp.maximum(way, 0), ccfg.num_ways)
-    slots = jnp.where(resident, slots, 0)
+    slot buffer (fast tier), others from the host table (slow tier).
+
+    A set holds at most ``num_ways`` experts, so at most that many groups
+    are resident: their slot rows are written over the host gather,
+    instead of gathering all G groups from both tiers and selecting.
+    At Mixtral widths and G = 8 that keeps one [G, D, F] gather per
+    matrix off the decode step's HBM peak (~2.5 GB)."""
+    G = pr.rep_e.shape[0]
     e_ix = jnp.maximum(pr.rep_e, 0)
-    r3 = resident[:, None, None]
-    host_w1 = tiers.host_w1[layer, e_ix]
-    host_w3 = tiers.host_w3[layer, e_ix]
-    host_w2 = tiers.host_w2[layer, e_ix]
-    w1 = jnp.where(r3, tiers.slot_w1[slots], host_w1)
-    w3 = jnp.where(r3, tiers.slot_w3[slots], host_w3)
-    w2 = jnp.where(r3, tiers.slot_w2[slots], host_w2)
-    return (w1, w3, w2), (host_w1, host_w3, host_w2)
+    host_w = (tiers.host_w1[layer, e_ix], tiers.host_w3[layer, e_ix],
+              tiers.host_w2[layer, e_ix])
+    res_g = jnp.nonzero(pr.resident, size=min(ccfg.num_ways, G),
+                        fill_value=G)[0]             # G = none: dropped
+    way = pr.res_way[jnp.minimum(res_g, G - 1)]
+    slots = cache_lib.slot_id(layer, jnp.maximum(way, 0), ccfg.num_ways)
+    w = tuple(h.at[res_g].set(s[slots], mode="drop") for h, s in
+              zip(host_w, (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2)))
+    return w, host_w
 
 
 def _stage_dispatch(x: jax.Array, K: int, pr: ProbeResult

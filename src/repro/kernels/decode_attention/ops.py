@@ -7,14 +7,14 @@ import jax
 import jax.numpy as jnp
 
 from .decode_attention import flash_decode
+from .. import interpret_mode
 from .paged import paged_flash_decode
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("window",))
 def _decode_attention(q, k, v, pos, window):
-    return flash_decode(q, k, v, pos, window=window, interpret=INTERPRET)
+    return flash_decode(q, k, v, pos, window=window,
+                        interpret=interpret_mode())
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -41,7 +41,7 @@ def _paged_decode_attention(q, k_pages, v_pages, page_indptr, page_indices,
     return paged_flash_decode(q, k_pages, v_pages, page_indptr,
                               page_indices, last_page_len,
                               max_pages=max_pages, window=window,
-                              interpret=INTERPRET)
+                              interpret=interpret_mode())
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,

@@ -3,16 +3,16 @@
 `moe_ffn` runs the full grouped SwiGLU expert FFN on the [E, C, D]
 dispatch buffer: fused gate kernel + down-projection gmm. All dims are
 padded to 128 multiples here (MXU tile), so callers never think about
-tiling. On non-TPU backends (this container) interpret mode is used.
+tiling. Interpret mode only where :func:`repro.kernels.interpret_mode`
+says so (a CPU backend chosen with ``JAX_PLATFORMS=cpu``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .moe_gmm import gmm, swiglu_gmm
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def _pad128(x: jax.Array, *axes: int) -> jax.Array:
@@ -32,8 +32,8 @@ def moe_ffn(x: jax.Array, w1: jax.Array, w3: jax.Array,
     E, C, D = x.shape
     xp = _pad128(x, 1, 2)
     h = swiglu_gmm(xp, _pad128(w1, 1, 2), _pad128(w3, 1, 2),
-                   interpret=INTERPRET)
-    y = gmm(h, _pad128(w2, 1, 2), interpret=INTERPRET)
+                   interpret=interpret_mode())
+    y = gmm(h, _pad128(w2, 1, 2), interpret=interpret_mode())
     return y[:, :C, :D]
 
 
@@ -42,5 +42,5 @@ def grouped_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
     """Padded grouped matmul wrapper: [E, C, D] @ [E, D, F]."""
     _, C, _ = x.shape
     F = w.shape[-1]
-    out = gmm(_pad128(x, 1, 2), _pad128(w, 1, 2), interpret=INTERPRET)
+    out = gmm(_pad128(x, 1, 2), _pad128(w, 1, 2), interpret=interpret_mode())
     return out[:, :C, :F]

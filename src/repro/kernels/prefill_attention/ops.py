@@ -6,9 +6,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .paged import paged_flash_prefill
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("max_pages", "window"))
@@ -17,7 +16,7 @@ def _paged_prefill_attention(q, k_pages, v_pages, page_indptr, page_indices,
     return paged_flash_prefill(q, k_pages, v_pages, page_indptr,
                                page_indices, last_page_len, pos0,
                                max_pages=max_pages, window=window,
-                               interpret=INTERPRET)
+                               interpret=interpret_mode())
 
 
 def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,

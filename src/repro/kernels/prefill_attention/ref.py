@@ -55,14 +55,15 @@ def paged_prefill_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     scale = hd ** -0.5
 
     def replay(q, k_pages, v_pages):
-        qg = q.reshape(B, C, Hk, group, hd).transpose(0, 2, 1, 3, 4)
+        qg = q.reshape(B, C, Hk, group, hd).transpose(0, 2, 1, 3, 4).reshape(
+            B, Hk, C * group, hd)
         rows = []
         for b in range(B):
             n_pages = int(indptr[b + 1] - indptr[b])
             last = (n_pages - 1) * page_size + int(lastlen[b]) - 1
             heads = []
             for h in range(Hk):
-                qf = qg[b, h].astype(jnp.float32).reshape(C * group, hd)
+                qf = qg[b, h].astype(jnp.float32)
                 m = jnp.full((C * group, 1), -1e30, jnp.float32)
                 l = jnp.zeros((C * group, 1), jnp.float32)
                 acc = jnp.zeros((C * group, hd), jnp.float32)
@@ -87,9 +88,9 @@ def paged_prefill_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     acc = acc * alpha + jnp.dot(
                         p, v, preferred_element_type=jnp.float32)
                     m = m_new
-                heads.append((acc / jnp.maximum(l, 1e-30)
-                              ).reshape(C, group, hd).astype(q.dtype))
+                heads.append((acc / jnp.maximum(l, 1e-30)).astype(q.dtype))
             rows.append(jnp.stack(heads))
-        return jnp.stack(rows).transpose(0, 2, 1, 3, 4).reshape(B, C, H, hd)
+        return jnp.stack(rows).reshape(B, Hk, C, group, hd).transpose(
+            0, 2, 1, 3, 4).reshape(B, C, H, hd)
 
     return jax.jit(replay)(q, k_pages, v_pages)

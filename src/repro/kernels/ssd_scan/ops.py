@@ -4,9 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .ssd_scan import ssd_scan
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 @jax.jit
@@ -30,6 +29,6 @@ def ssd_chunked_kernel(x: jax.Array, dt: jax.Array, A_log: jax.Array,
     cG = jnp.broadcast_to(Cmat[:, None], (Bb, nh, S, ds)).reshape(
         Bb * nh, S, ds).astype(x.dtype)
 
-    y, h = ssd_scan(aG, xG, bG, cG, interpret=INTERPRET)
+    y, h = ssd_scan(aG, xG, bG, cG, interpret=interpret_mode())
     y = y.reshape(Bb, nh, S, hp).transpose(0, 2, 1, 3)
     return y.astype(x.dtype), h.reshape(Bb, nh, ds, hp)

@@ -7,10 +7,12 @@ continuous batching, or the plain generic path for non-MoE archs.
         [--prefetch --prefetch-min-prob 0.2] \
         [--host-compute --host-threads 8 --host-backend callback] \
         [--kv-paged --page-size 16 --kv-pages 64] \
-        [--prefill-segment 8 --prefix-keep-pages 16]
+        [--prefill-segment 8 --prefix-keep-pages 16] [--layers 2]
 
-Reduced configs by default (this is a CPU container); the full configs are
-exercised via the dry-run. Prints tokens/s and the paper's cache counters.
+Without ``--layers`` the model is ``reduced()`` (tiny widths, for the CPU);
+``--layers N`` serves the published config at every published width with
+only its depth cut to N layers (for the chip). Prints tokens/s and the
+paper's cache counters.
 ``--temperature > 0`` turns on per-request sampling (seeded per request:
 request r uses seed ``--seed + r``); the default is greedy decoding.
 """
@@ -23,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import get_config, reduced
+from repro.config import get_config, reduced, with_layers
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decode_step, init_params, prefill
 from repro.obs import TraceRecorder, write_chrome_trace
 from repro.serving import SamplingParams, build
@@ -32,6 +35,10 @@ from repro.serving import SamplingParams, build
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve the published config at its published "
+                         "widths, cut to this many layers (default: the "
+                         "tiny reduced() geometry)")
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--prompt", type=int, default=32)
@@ -125,7 +132,15 @@ def main() -> None:
     if args.temperature < 0:
         ap.error(f"--temperature must be >= 0, got {args.temperature}")
 
-    cfg = reduced(get_config(args.arch))
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.layers is None:
+        cfg = reduced(cfg)
+    else:
+        try:
+            cfg = with_layers(cfg, args.layers)
+        except ValueError as e:
+            ap.error(str(e))
     key = jax.random.PRNGKey(args.seed)
     params = init_params(cfg, key)
     prompt = np.asarray(

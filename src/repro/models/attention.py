@@ -5,8 +5,9 @@ Design notes
 * Prefill/train uses a pure-XLA *chunked flash* formulation: ``lax.scan``
   over KV chunks with online-softmax running statistics. Peak memory is
   O(S * chunk) instead of O(S^2), which is what makes the 32k-prefill cells
-  compile within HBM. The Pallas TPU kernel (kernels/decode_attention) is a
-  drop-in replacement for the decode einsum path on real hardware.
+  compile within HBM. Paged segment prefill scores with the Pallas kernel
+  (kernels/prefill_attention); decode uses the einsum path below, dense
+  and paged alike — the decode-attention kernels are on no serving path.
 * Decode (q_len == 1) uses exact einsum attention over the cache capacity
   with a position mask; scores are [B, H, 1, S] which is small. The cache
   is updated in place at ``pos`` via dynamic_update_slice (donated buffer).

@@ -293,15 +293,15 @@ class CollaborativeEngine:
         key = key if key is not None else jax.random.PRNGKey(0)
 
         # Split expert weights out of the param tree into the two tiers.
-        # The host tier is read-only and aliases the param tree — it is
-        # deliberately NOT donated (donating it would delete the params'
-        # buffers under prefill's feet); only the mutable fast-tier state
-        # (slot buffers + tags/age) threads through with donation.
+        # The host tier is read-only and IS the param tree's expert table
+        # (see _tiers) — it is deliberately NOT donated (donating it would
+        # delete the params' buffers under prefill's feet); only the
+        # mutable fast-tier state (slot buffers + tags/age) threads
+        # through with donation.
         moe_p = params["scan"]["s0"]["moe"]
         tiers = collab.init_tiers(
             moe_p["w1"], moe_p["w3"], moe_p["w2"], ecfg.cache,
             num_experts=cfg.moe.num_experts, key=key)
-        self._host = (tiers.host_w1, tiers.host_w3, tiers.host_w2)
         self.fast = (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2, tiers.state)
 
         # live host execution: cost-model split table + (callback backend)
@@ -338,15 +338,18 @@ class CollaborativeEngine:
         self._slot_tables = [None] * ecfg.max_batch
         self._slot_pages: Optional[np.ndarray] = None
 
-        self._decode = jax.jit(self._decode_step, donate_argnums=(1, 2))
+        # every jitted step takes the params as its first argument: a
+        # closed-over param tree would be baked into the program as
+        # constants (gigabytes of HLO at published widths)
+        self._decode = jax.jit(self._decode_step, donate_argnums=(2, 3))
         self._write = jax.jit(self._write_slot, donate_argnums=(0,))
         self._write_paged = jax.jit(self._write_slot_paged,
                                     donate_argnums=(0,))
         self._cow = jax.jit(self._copy_page, donate_argnums=(0,))
         self._prefill = jax.jit(self._prefill_trace,
                                 static_argnames=("want_trace",))
-        self._warm = jax.jit(self._warm_chunk, donate_argnums=(0,))
-        self._segment = jax.jit(self._segment_step, donate_argnums=(1, 2),
+        self._warm = jax.jit(self._warm_chunk, donate_argnums=(1,))
+        self._segment = jax.jit(self._segment_step, donate_argnums=(2, 3),
                                 static_argnames=("warm",))
         L = cfg.num_layers
         self._counters = {
@@ -390,15 +393,18 @@ class CollaborativeEngine:
             per_layer_accesses=tuple(int(x) for x in self._per_layer_accesses),
             **c)
 
-    def _tiers(self, fast) -> collab.ExpertTiers:
+    @staticmethod
+    def _tiers(params, fast) -> collab.ExpertTiers:
         s1, s3, s2, state = fast
-        h1, h3, h2 = self._host
-        return collab.ExpertTiers(host_w1=h1, host_w3=h3, host_w2=h2,
+        moe_p = params["scan"]["s0"]["moe"]
+        return collab.ExpertTiers(host_w1=moe_p["w1"], host_w3=moe_p["w3"],
+                                  host_w2=moe_p["w2"],
                                   slot_w1=s1, slot_w3=s3, slot_w2=s2,
                                   state=state)
 
     # -- one decode step with the staged collaborative pipeline -----------
-    def _decode_step(self, tokens, state, fast, active, pages=None):
+    def _decode_step(self, params, tokens, state, fast, active,
+                     pages=None):
         """tokens [T, 1]; state['pos'] [T] per-slot positions; active [T]
         bool — padded slots neither touch the shared cache nor the stats;
         pages [T, max_pages] int32 per-slot physical page ids (paged KV
@@ -413,8 +419,7 @@ class CollaborativeEngine:
         against the *actual* next-layer routing."""
         cfg = self.cfg
         ccfg = self.ecfg.cache
-        params = self.params
-        tiers = self._tiers(fast)
+        tiers = self._tiers(params, fast)
         x = transformer._embed_inputs(params, {"tokens": tokens}, cfg)
         pos = state["pos"]
         slots, _, _ = transformer.build_slots(cfg)
@@ -717,7 +722,7 @@ class CollaborativeEngine:
         return {"scan": batch_state["scan"], "pos": pos}
 
     # -- prefill: one shared trace, two cache modes ------------------------
-    def _prefill_trace(self, tokens, plen, want_trace: bool = False):
+    def _prefill_trace(self, params, tokens, plen, want_trace: bool = False):
         """Full-prompt forward: the backbone's prefill mode, directly.
 
         tokens [B, capacity] (prompt left-aligned, zero-padded); plen —
@@ -736,10 +741,10 @@ class CollaborativeEngine:
         """
         cfg = self.cfg
         x, state, _, trace = transformer.backbone(
-            self.params, {"tokens": tokens}, cfg, "prefill", remat=False,
+            params, {"tokens": tokens}, cfg, "prefill", remat=False,
             want_trace=want_trace)
         h_last = jax.lax.dynamic_slice_in_dim(x, plen - 1, 1, axis=1)
-        logits = transformer.lm_logits(self.params, h_last, cfg)
+        logits = transformer.lm_logits(params, h_last, cfg)
         state = {"scan": state["scan"], "pos": jnp.asarray(plen, jnp.int32)}
         # homogeneous stack: the one scanned slot's trace IS the engine's
         # [L, B, S, ...] routing trace
@@ -757,7 +762,8 @@ class CollaborativeEngine:
                 f"prompt length {P} outside [1, capacity={cap}) — decode "
                 f"needs at least one free KV slot")
         pad = jnp.zeros((B, cap - P), tokens.dtype)
-        return self._prefill(jnp.concatenate([tokens, pad], 1),
+        return self._prefill(self.params,
+                             jnp.concatenate([tokens, pad], 1),
                              jnp.asarray(P, jnp.int32),
                              want_trace=want_trace)
 
@@ -779,7 +785,7 @@ class CollaborativeEngine:
                 f"use the scheduler primitives (start_prefill / bind_slot "
                 f"/ decode_batch / release_slot)")
 
-    def _warm_chunk(self, fast, top_i, top_w, h2, active):
+    def _warm_chunk(self, params, fast, top_i, top_w, h2, active):
         """Route one prompt chunk through probe → execute → commit.
 
         top_i/top_w [L, C, K]; h2 [L, C, D]; active [C] (False = pad rows
@@ -793,7 +799,7 @@ class CollaborativeEngine:
         weight gathers and slot writes. Returns (fast, per-layer stats).
         """
         ccfg = self.ecfg.cache
-        tiers = self._tiers(fast)
+        tiers = self._tiers(params, fast)
 
         def body(carry, xs):
             tiers, layer = carry
@@ -809,8 +815,8 @@ class CollaborativeEngine:
         new_fast = (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2, tiers.state)
         return new_fast, stats
 
-    def _segment_step(self, tokens, scan_state, fast, pos0, plen, pages,
-                      wmin, warm: bool = True):
+    def _segment_step(self, params, tokens, scan_state, fast, pos0, plen,
+                      pages, wmin, warm: bool = True):
         """One C-token prompt segment, forward + warm fused.
 
         Runs the backbone's segment mode: the segment attends to the
@@ -834,18 +840,18 @@ class CollaborativeEngine:
         C = tokens.shape[1]
         state = {"scan": scan_state, "pos": pos0}
         x, new_state, _, trace = transformer.backbone(
-            self.params, {"tokens": tokens}, cfg, "segment", state=state,
+            params, {"tokens": tokens}, cfg, "segment", state=state,
             remat=False, want_trace=warm, pages=pages,
             kv_write_min=wmin, kv_write_max=plen)
         rel = jnp.clip(plen - 1 - pos0, 0, C - 1)
         h_last = jax.lax.dynamic_slice_in_dim(x, rel, 1, axis=1)
-        logits = transformer.lm_logits(self.params, h_last, cfg)
+        logits = transformer.lm_logits(params, h_last, cfg)
         wstats = None
         if warm:
             tr = trace["scan"]["s0"]
             active = (pos0 + jnp.arange(C)) < plen
             fast, wstats = self._warm_chunk(
-                fast, tr["top_i"][:, 0], tr["top_w"][:, 0],
+                params, fast, tr["top_i"][:, 0], tr["top_w"][:, 0],
                 tr["h2"][:, 0], active)
         new_pos = jnp.minimum(new_state["pos"], plen)
         return logits, new_state["scan"], fast, new_pos, wstats
@@ -1015,13 +1021,14 @@ class CollaborativeEngine:
                     pages = jnp.asarray(ticket.page_ids[None])
                     wmin = jnp.asarray(ticket.shared_tokens, jnp.int32)
                     logits, new_scan, self.fast, _, wstats = self._segment(
-                        tok, batch_state["scan"], self.fast, pos0, plen,
-                        pages, wmin, warm=ticket.warm)
+                        self.params, tok, batch_state["scan"], self.fast,
+                        pos0, plen, pages, wmin, warm=ticket.warm)
                     batch_state = {"scan": new_scan,
                                    "pos": batch_state["pos"]}
                 else:
                     logits, new_scan, self.fast, new_pos, wstats = \
-                        self._segment(tok, ticket.state["scan"], self.fast,
+                        self._segment(self.params, tok,
+                                      ticket.state["scan"], self.fast,
                                       pos0, plen, None, None,
                                       warm=ticket.warm)
                     ticket.state = {"scan": new_scan, "pos": new_pos}
@@ -1040,7 +1047,7 @@ class CollaborativeEngine:
             s = ticket.cursor * chunk
             active = jnp.arange(s, s + chunk) < P
             self.fast, wstats = self._warm(
-                self.fast, ticket.top_i[:, s:s + chunk],
+                self.params, self.fast, ticket.top_i[:, s:s + chunk],
                 ticket.top_w[:, s:s + chunk], ticket.h2[:, s:s + chunk],
                 active)
             advanced.append((wstats, min(chunk, P - s)))
@@ -1177,7 +1184,8 @@ class CollaborativeEngine:
             pages = jnp.asarray(self._slot_pages)
         t_plan = now_ns()
         logits, state, self.fast, stats = self._decode(
-            jnp.asarray(tokens, jnp.int32), state, self.fast, active, pages)
+            self.params, jnp.asarray(tokens, jnp.int32), state, self.fast,
+            active, pages)
         t_disp = now_ns()                 # async dispatch returned
         if self.ecfg.kv_paged:
             for t in act:
@@ -1321,8 +1329,8 @@ class CollaborativeEngine:
         active = jnp.ones((B,), bool)
         out = [np.asarray(tok)]
         for i in range(steps - 1):
-            logits, state, self.fast, stats = self._decode(tok, state,
-                                                           self.fast, active)
+            logits, state, self.fast, stats = self._decode(
+                self.params, tok, state, self.fast, active)
             tok = self.select_tokens(logits[:, 0], sampling,
                                      step_keys(i + 1))[:, None]
             out.append(np.asarray(tok))

@@ -96,7 +96,8 @@ def test_padded_slots_are_bitwise_invisible(setup):
         tokens[0, 0] = tok
         active = np.array([True, False, False, False])
         logits, _, fast, stats = eng._decode(
-            jnp.asarray(tokens), state, fast0, jnp.asarray(active))
+            eng.params, jnp.asarray(tokens), state, fast0,
+            jnp.asarray(active))
         return (np.asarray(logits[0, 0]), jax.tree.map(np.asarray, fast),
                 {k: int(np.asarray(v).sum()) for k, v in stats.items()})
 

@@ -1,0 +1,88 @@
+"""AOT compiles of the serving kernels for a described TPU v5e chip.
+
+Interpret mode never checks Mosaic's rules (block shapes whose last two
+dims are neither (8, 128)-divisible nor whole, unprovable alignment, VMEM
+budget), so every other kernel test passes on kernels the chip would
+refuse. Here each kernel is compiled at Mixtral-8x7B widths (D=4096,
+F=14336, H=32, Hk=8, hd=128, page 16) for a v5e chip that is described,
+not attached. The kernel functions are called with ``interpret=False``
+directly: the ops.py wrappers see the CPU backend here.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU compiler library,
+and under xdist only the worker that runs this file does.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention.decode_attention import flash_decode
+from repro.kernels.decode_attention.paged import paged_flash_decode
+from repro.kernels.moe_gmm.moe_gmm import gmm, swiglu_gmm
+from repro.kernels.prefill_attention.paged import paged_flash_prefill
+
+B, C, H, HK, HD, PAGE, MAX_PAGES = 4, 16, 32, 8, 128, 16, 6
+NUM_PAGES = B * MAX_PAGES
+E, ROWS, D, F = 8, 128, 4096, 14336
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")      # else the compiler logs to /tmp
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(one_chip, *specs):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+
+
+bf16, i32 = jnp.bfloat16, jnp.int32
+KV = ((NUM_PAGES, PAGE, HK, HD), bf16)
+CSR = [((B + 1,), i32), ((NUM_PAGES,), i32), ((B,), i32)]
+
+CASES = {
+    "swiglu_gmm": (lambda x, w1, w3: swiglu_gmm(x, w1, w3),
+                   [((E, ROWS, D), bf16), ((E, D, F), bf16),
+                    ((E, D, F), bf16)]),
+    "gmm": (lambda x, w: gmm(x, w),
+            [((E, ROWS, D), bf16), ((E, D, F), bf16)]),
+    "paged_flash_prefill": (
+        lambda q, k, v, ip, ix, ll, p0: paged_flash_prefill(
+            q, k, v, ip, ix, ll, p0, max_pages=MAX_PAGES),
+        [((B, C, H, HD), bf16), KV, KV, *CSR, ((B,), i32)]),
+    "paged_flash_decode": (
+        lambda q, k, v, ip, ix, ll: paged_flash_decode(
+            q, k, v, ip, ix, ll, max_pages=MAX_PAGES),
+        [((B, H, HD), bf16), KV, KV, *CSR]),
+    "flash_decode": (
+        lambda q, k, v, pos: flash_decode(q, k, v, pos),
+        [((B, H, HD), bf16), ((B, 2048, HK, HD), bf16),
+         ((B, 2048, HK, HD), bf16), ((B,), i32)]),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, specs = CASES[name]
+    compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name

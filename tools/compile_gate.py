@@ -78,10 +78,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = ap.parse_args(argv)
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_log_compiles", True)
     log = CompileLog()
-    # jax 0.4.x emits "Compiling <fn> with global shapes and types [...]"
-    # on this logger at WARNING when jax_log_compiles is set
+    # jax emits "Compiling <fn> with global shapes and types [...]" on
+    # this logger at WARNING when jax_log_compiles is set
     logging.getLogger("jax._src.interpreters.pxla").addHandler(log)
     # drop the per-compile "Finished tracing/compilation" timing spam the
     # same flag turns on — the gate only needs the Compiling records
