@@ -30,6 +30,14 @@ decomposed into composable stages:
 prefetch); the serving engine drives the stages directly so it can overlap
 the prefetch for layer *l+1* with layer *l*'s commit.
 
+Each stage runs under a ``jax.named_scope`` — ``moe_probe``,
+``moe_gather`` (the per-unique-expert weight gather), ``moe_dispatch``
+(dispatch buffer and combine), ``moe_experts`` (the grouped kernels),
+``moe_commit`` and ``moe_prefetch`` — so every HLO instruction a stage
+emits names it in its ``op_name`` metadata, in the decode, segment and
+warm-replay programs alike. Scopes are metadata: the compiled program is
+the same without them.
+
 The seed implementation executed every assignment separately (dense
 per-assignment weight gathers + a vmapped single-row FFN) — it is retained
 as :func:`collaborative_moe_reference` for parity tests and benchmarks.
@@ -211,6 +219,7 @@ class ProbeResult(NamedTuple):
     res_way: jax.Array
 
 
+@jax.named_scope("moe_probe")
 def probe(tiers: ExpertTiers, layer: jax.Array, top_i: jax.Array,
           ccfg: CacheConfig,
           active: Optional[jax.Array] = None) -> ProbeResult:
@@ -237,6 +246,7 @@ def probe(tiers: ExpertTiers, layer: jax.Array, top_i: jax.Array,
                        rep_e=rep_e, resident=resident, res_way=res_way)
 
 
+@jax.named_scope("moe_gather")
 def _gather_group_weights(tiers: ExpertTiers, layer, pr: ProbeResult,
                           ccfg: CacheConfig):
     """Gather each unique expert's weights once — resident experts from the
@@ -260,6 +270,7 @@ def _gather_group_weights(tiers: ExpertTiers, layer, pr: ProbeResult,
     return w, host_w
 
 
+@jax.named_scope("moe_dispatch")
 def _stage_dispatch(x: jax.Array, K: int, pr: ProbeResult
                     ) -> Tuple[jax.Array, jax.Array]:
     """Assemble the [G, A, D] per-unique-expert dispatch buffer for one
@@ -275,6 +286,14 @@ def _stage_dispatch(x: jax.Array, K: int, pr: ProbeResult
     return tok, xbuf
 
 
+@jax.named_scope("moe_experts")
+def experts(xbuf: jax.Array, w) -> jax.Array:
+    """The grouped SwiGLU kernels over the dispatch buffer, under the
+    ``moe_experts`` scope; the kernels keep their own instruction name
+    (``moe_ffn``)."""
+    return moe_ffn(xbuf, *w)
+
+
 def execute(tiers: ExpertTiers, layer: jax.Array, x: jax.Array,
             top_w: jax.Array, pr: ProbeResult, ccfg: CacheConfig
             ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array, jax.Array]]:
@@ -286,11 +305,12 @@ def execute(tiers: ExpertTiers, layer: jax.Array, x: jax.Array,
     T, K = top_w.shape
     tok, xbuf = _stage_dispatch(x, K, pr)
     w, host_w = _gather_group_weights(tiers, layer, pr, ccfg)
-    ybuf = moe_ffn(xbuf, *w)                               # [G, A, D]
+    ybuf = experts(xbuf, w)                                # [G, A, D]
     y = _combine(ybuf, pr.gid, pr.pos, tok, top_w, pr.valid, T, x.dtype)
     return y, host_w
 
 
+@jax.named_scope("moe_commit")
 def commit(tiers: ExpertTiers, layer: jax.Array, pr: ProbeResult, host_w,
            ccfg: CacheConfig) -> Tuple[ExpertTiers, jax.Array]:
     """Stage 3 — install the probe's cache state and post-fetch the newly
@@ -324,6 +344,7 @@ def prediction_votes(flat_p: jax.Array) -> jax.Array:
     return votes.astype(jnp.int32)
 
 
+@jax.named_scope("moe_prefetch")
 def prefetch(tiers: ExpertTiers, layer: jax.Array, pred_i: jax.Array,
              ccfg: CacheConfig, active: Optional[jax.Array] = None,
              rank_votes: bool = False
@@ -396,6 +417,7 @@ def _post_fetch(tiers: ExpertTiers, layer, rep_e, resident, res_way,
     return s_w1, s_w3, s_w2, fetch
 
 
+@jax.named_scope("moe_dispatch")
 def _combine(ybuf, gid, pos, tok, top_w, valid, T, x_dtype):
     ya = ybuf[gid, pos]
     scale = top_w.reshape(-1) * valid.astype(jnp.float32)

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 
 from repro.config import CacheConfig
 from repro.core import collaborative as collab
-from repro.kernels.moe_gmm.ops import moe_ffn
 
 from .executor import HostExpertExecutor
 
@@ -94,7 +93,7 @@ def dispatch_execute(tiers: collab.ExpertTiers, layer: jax.Array,
 
     # device lane: grouped gmm over the tiered gather (hit groups read the
     # slot buffer, fetch-set misses the host tier — unchanged)
-    ybuf_dev = moe_ffn(xbuf, *w)                           # [G, A, D]
+    ybuf_dev = collab.experts(xbuf, w)                     # [G, A, D]
 
     if executor is not None:
         # host lane: the activation buffer crosses to the CPU executor

@@ -12,7 +12,15 @@ serving stack records *timelines*, not just end-of-run counters:
   raw samples.
 * ``export``  — Chrome trace-event JSON (loadable in Perfetto /
   ``chrome://tracing``) with one track per request, per slot and per
-  dispatch lane, and a structural validator CI runs on the artifact.
+  lane, and a structural validator CI runs on the artifact.
+
+A decode step's lane attribution (hit, fetched and cpu-lane experts)
+rides the ``engine/decode_step`` span's args; the ``lane:*`` tracks carry
+prefetch reservations and landings and the host executor's busy time.
+Between two decode steps the leaf spans — ``engine/drain``,
+``sched/select``, ``sched/emit``, ``sched/admission``, ``engine/plan``,
+``engine/dispatch`` — tile the host's time, and ``engine/wait`` covers
+the device running the step.
 
 Drain-point rule (enforced by reprolint RL007): emission calls —
 ``complete`` / ``instant`` / ``counter`` / ``span`` — are only legal at

@@ -5,7 +5,8 @@ ring into the Chrome trace-event format (the JSON-array-of-events
 dialect wrapped in ``{"traceEvents": [...]}``) loadable in Perfetto or
 ``chrome://tracing``. Every track string becomes its own named thread
 under one process, so requests (``req:N``), slots (``slot:N``) and
-dispatch lanes (``lane:*``) render as parallel swimlanes; timestamps
+lanes (``lane:*``: prefetch reservations and landings, host-executor
+busy time) render as parallel swimlanes; timestamps
 are rebased to the recorder's epoch and expressed in microseconds as
 the format requires.
 

@@ -416,11 +416,17 @@ class CollaborativeEngine:
         reservations + weight streams so the next probe finds them
         resident. The prediction and the issued-fetch set ride the scan
         carry one iteration so accuracy and wasted fetches are scored
-        against the *actual* next-layer routing."""
+        against the *actual* next-layer routing.
+
+        Each stage runs under a ``jax.named_scope`` (``embed``, ``attn``,
+        ``router``, ``moe_prefetch``, ``lm_head`` here; the MoE stages name
+        theirs in ``core/collaborative.py``), so the device trace can sum
+        a step's time by stage."""
         cfg = self.cfg
         ccfg = self.ecfg.cache
         tiers = self._tiers(params, fast)
-        x = transformer._embed_inputs(params, {"tokens": tokens}, cfg)
+        with jax.named_scope("embed"):
+            x = transformer._embed_inputs(params, {"tokens": tokens}, cfg)
         pos = state["pos"]
         slots, _, _ = transformer.build_slots(cfg)
         slot = slots[0]
@@ -438,26 +444,30 @@ class CollaborativeEngine:
             # — the next token's layer-0 input is unknowable before
             # sampling. Only the prefetch build pays for the rolled
             # weight-table duplicates.
-            xs.update(
-                ln2_next=jnp.roll(scan_p["ln2"], -1, axis=0),
-                router_next=jnp.roll(scan_p["moe"]["router"], -1, axis=0),
-                has_next=jnp.arange(cfg.num_layers) < cfg.num_layers - 1)
+            with jax.named_scope("moe_prefetch"):
+                xs.update(
+                    ln2_next=jnp.roll(scan_p["ln2"], -1, axis=0),
+                    router_next=jnp.roll(scan_p["moe"]["router"], -1,
+                                         axis=0),
+                    has_next=jnp.arange(cfg.num_layers) < cfg.num_layers - 1)
 
         def body(carry, xs):
             x, tiers, layer, pred_prev, rep_prev, issued_prev = carry
             lp, st = xs["params"], xs["state"]
-            h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-            if self.ecfg.kv_paged:
-                o, new_st = attn.decode_attention_paged(
-                    lp["attn"], h, st, pos, pages, cfg, slot.window,
-                    active=active)
-            else:
-                o, new_st = attn.decode_attention(lp["attn"], h, st, pos,
-                                                  cfg, slot.window)
-            x = x + o
-            h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
-            _, top_i, top_w = route(lp["moe"]["router"],
-                                    h2[:, 0].astype(jnp.float32), K)
+            with jax.named_scope("attn"):
+                h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+                if self.ecfg.kv_paged:
+                    o, new_st = attn.decode_attention_paged(
+                        lp["attn"], h, st, pos, pages, cfg, slot.window,
+                        active=active)
+                else:
+                    o, new_st = attn.decode_attention(
+                        lp["attn"], h, st, pos, cfg, slot.window)
+                x = x + o
+            with jax.named_scope("router"):
+                h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                _, top_i, top_w = route(lp["moe"]["router"],
+                                        h2[:, 0].astype(jnp.float32), K)
 
             # staged collaborative MoE: probe -> dispatch/execute -> commit
             pr = collab.probe(tiers, layer, top_i, ccfg, active=active)
@@ -480,34 +490,38 @@ class CollaborativeEngine:
             x = x + y[:, None].astype(x.dtype)
 
             if self.ecfg.prefetch:
-                # score the prediction the previous iteration made for
-                # THIS layer: accuracy per predicted assignment, and
-                # issued fetches whose expert the layer never demanded
-                pred_valid = (pred_prev >= 0) & active[:, None]
-                pred_ok = (pred_prev[:, :, None]
-                           == top_i[:, None, :]).any(-1)
-                demanded = (rep_prev[:, None] == pr.flat_e[None, :]).any(-1)
-                wasted = (issued_prev & ~demanded).sum()
-                predicted = pred_valid.sum()
-                pred_correct = (pred_ok & pred_valid).sum()
-                # speculative prefetch for layer l+1 (reservations +
-                # streams; invisible until the next probe lands them).
-                # Pre-gating prediction: layer l+1's router on layer l's
-                # OUTPUT residual (its true input one attention block
-                # later) — the DAOP-style one-layer lookahead; the
-                # reservation's transfer hides under layer l+1's attention
-                h_pred = rmsnorm(xs["ln2_next"], x, cfg.norm_eps)
-                pred_p, pred_i, _ = route(xs["router_next"],
-                                          h_pred[:, 0].astype(jnp.float32), K)
-                gate = xs["has_next"] & active[:, None]
-                if self.ecfg.prefetch_min_prob > 0.0:
-                    # confidence gate: only reserve picks whose router
-                    # probability clears the threshold — mispredictions
-                    # are the only source of cache pollution, and low-
-                    # confidence picks are where they live
-                    p_pick = jnp.take_along_axis(pred_p, pred_i, axis=1)
-                    gate = gate & (p_pick >= self.ecfg.prefetch_min_prob)
-                pred_i = jnp.where(gate, pred_i, -1).astype(jnp.int32)
+                with jax.named_scope("moe_prefetch"):
+                    # score the prediction the previous iteration made for
+                    # THIS layer: accuracy per predicted assignment, and
+                    # issued fetches whose expert the layer never demanded
+                    pred_valid = (pred_prev >= 0) & active[:, None]
+                    pred_ok = (pred_prev[:, :, None]
+                               == top_i[:, None, :]).any(-1)
+                    demanded = (rep_prev[:, None]
+                                == pr.flat_e[None, :]).any(-1)
+                    wasted = (issued_prev & ~demanded).sum()
+                    predicted = pred_valid.sum()
+                    pred_correct = (pred_ok & pred_valid).sum()
+                    # speculative prefetch for layer l+1 (reservations +
+                    # streams; invisible until the next probe lands them).
+                    # Pre-gating prediction: layer l+1's router on layer l's
+                    # OUTPUT residual (its true input one attention block
+                    # later) — the DAOP-style one-layer lookahead; the
+                    # reservation's transfer hides under layer l+1's
+                    # attention
+                    h_pred = rmsnorm(xs["ln2_next"], x, cfg.norm_eps)
+                    pred_p, pred_i, _ = route(
+                        xs["router_next"], h_pred[:, 0].astype(jnp.float32),
+                        K)
+                    gate = xs["has_next"] & active[:, None]
+                    if self.ecfg.prefetch_min_prob > 0.0:
+                        # confidence gate: only reserve picks whose router
+                        # probability clears the threshold — mispredictions
+                        # are the only source of cache pollution, and low-
+                        # confidence picks are where they live
+                        p_pick = jnp.take_along_axis(pred_p, pred_i, axis=1)
+                        gate = gate & (p_pick >= self.ecfg.prefetch_min_prob)
+                    pred_i = jnp.where(gate, pred_i, -1).astype(jnp.int32)
                 tiers, rep_p, issued, n_issued = collab.prefetch(
                     tiers, layer + 1, pred_i, ccfg, active=active,
                     rank_votes=self.ecfg.prefetch_rank_votes)
@@ -536,12 +550,31 @@ class CollaborativeEngine:
                   jnp.full((NG,), -1, jnp.int32), jnp.zeros((NG,), bool))
         (x, tiers, _, _, _, _), (new_scan, stats) = jax.lax.scan(
             body, carry0, xs)
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = transformer.lm_logits(params, x, cfg)
+        with jax.named_scope("lm_head"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = transformer.lm_logits(params, x, cfg)
         new_state = {"scan": {"s0": new_scan},
                      "pos": pos + active.astype(jnp.int32)}
         new_fast = (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2, tiers.state)
         return logits, new_state, new_fast, stats
+
+    def lower_decode(self, params, state: Params, fast) -> jax.stages.Lowered:
+        """The jitted decode step lowered, not run, for ``params``, the slot
+        ``state`` and the expert tiers ``fast`` — arrays or
+        ``jax.ShapeDtypeStruct``s — at this engine's slot geometry, with
+        the token, mask and page-table arguments placed like ``params``'
+        first leaf. Its compiled text names every instruction with the
+        stage scope it came from, which a device trace of the step lacks."""
+        T = self.ecfg.max_batch
+        where = jax.tree.leaves(params)[0].sharding
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+
+        pages = (arg((T, self.max_pages), jnp.int32)
+                 if self.ecfg.kv_paged else None)
+        return self._decode.lower(params, arg((T, 1), jnp.int32), state,
+                                  fast, arg((T,), jnp.bool_), pages)
 
     # -- batch-state primitives for the scheduler -------------------------
     def init_slots(self) -> Params:
@@ -1191,6 +1224,10 @@ class CollaborativeEngine:
             for t in act:
                 self.kv_pool.commit_append(self._slot_tables[int(t)])
         t_commit = now_ns()
+        # the drain below blocks on these outputs anyway: waiting for them
+        # first splits the device's time from the host's reads
+        jax.block_until_ready((logits, stats))  # reprolint: allow[RL002] the drain's own wait, timed apart
+        t_wait = now_ns()
         c = self._counters
         snap = (c["hits"], c["fetched_experts"], c["cpu_expert_calls"],
                 c["prefetch_issued"], c["prefetch_hits"])
@@ -1198,8 +1235,8 @@ class CollaborativeEngine:
                  if self.host_executor is not None else 0)
         n_active = int(active_np.sum())
         self._accumulate(stats, n_active)
-        self._obs_decode(t0, t_plan, t_disp, t_commit, snap, busy0,
-                         n_active)
+        self._obs_decode((t0, t_plan, t_disp, t_commit, t_wait), snap,
+                         busy0, n_active)
         return logits, state
 
     def _accumulate(self, stats, n_active: int) -> None:
@@ -1229,33 +1266,28 @@ class CollaborativeEngine:
         c["prefill_tokens"] += n_tokens
 
     # -- trace drain helpers (the ONLY emission sites; see RL007) ----------
-    def _obs_decode(self, t0: int, t_plan: int, t_disp: int, t_commit: int,
-                    snap, busy0: int, n_active: int) -> None:
-        """Sanctioned drain point: emit the decode step's phase spans and
-        lane attribution AFTER ``_accumulate`` drained the step's stats.
-        Device work is timed by bracketing the jitted call at the drain
-        (dispatch returns asynchronously; the drain's device_get blocks
-        until the step completes), never by syncing inside it."""
+    def _obs_decode(self, marks: Tuple[int, int, int, int, int], snap,
+                    busy0: int, n_active: int) -> None:
+        """Sanctioned drain point: emit the decode step's spans AFTER
+        ``_accumulate`` drained the step's stats. ``marks`` are the clock
+        readings that end each host phase of the step; the leaf spans
+        ``plan`` / ``dispatch`` / ``commit`` / ``wait`` (the device
+        running the step) / ``drain`` (the stats reads) tile
+        ``decode_step`` end to end. The step's lane attribution (hit,
+        fetched and cpu-lane experts) rides ``decode_step``'s args."""
         t1 = now_ns()
         obs = self.obs
         c = self._counters
         hit = c["hits"] - snap[0]
         fetch = c["fetched_experts"] - snap[1]
         cpu = c["cpu_expert_calls"] - snap[2]
-        obs.complete("engine", "decode_step", t0, t1,
+        obs.complete("engine", "decode_step", marks[0], t1,
                      {"tokens": n_active, "hit_experts": hit,
                       "fetched_experts": fetch, "cpu_expert_calls": cpu})
-        if self.ecfg.kv_paged:
-            obs.complete("engine", "plan", t0, t_plan)
-        obs.complete("engine", "dispatch", t_plan, t_disp)
-        if self.ecfg.kv_paged:
-            obs.complete("engine", "commit", t_disp, t_commit)
-        obs.complete("engine", "execute+drain", t_commit, t1)
-        # per-step lane attribution from the probe/census counters: the
-        # gpu-hit vs fetch vs cpu-miss split of this step's assignments
-        obs.counter("lane:gpu", "hit_experts", hit, ts_ns=t1)
-        obs.counter("lane:fetch", "fetched_experts", fetch, ts_ns=t1)
-        obs.counter("lane:cpu", "cpu_expert_calls", cpu, ts_ns=t1)
+        edges = (*marks, t1)
+        for name, a, b in zip(("plan", "dispatch", "commit", "wait",
+                               "drain"), edges, edges[1:]):
+            obs.complete("engine", name, a, b)
         if c["prefetch_issued"] - snap[3]:
             obs.instant("lane:fetch", "prefetch_reserve",
                         {"issued": c["prefetch_issued"] - snap[3]},

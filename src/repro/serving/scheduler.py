@@ -538,6 +538,7 @@ class ContinuousBatchingScheduler:
         # request just now: retire it before the decode step so its slot
         # neither decodes a phantom token nor blocks a later admission
         finished += self._retire()
+        active = self.decode_mask
         t_adm1 = now_ns()
         if admitted or warming:
             # the admission-stall sample: time this tick spent on
@@ -545,11 +546,12 @@ class ContinuousBatchingScheduler:
             # that the established slots' decode step had to wait behind
             self._h_stall.observe((t_adm1 - t_adm0) / 1e6)
         decoded = 0
-        active = self.decode_mask
+        t_sel = t_emit = t_adm1
         if active.any():
             decoded = int(active.sum())
             logits, self.state = self.engine.decode_batch(
                 self._next, self.state, active)
+            t_sel = now_ns()
             params = [r.sampling if r is not None and tk is None else GREEDY
                       for r, tk in zip(self.slots, self._tickets)]
             if all(p.greedy for p in params):
@@ -563,31 +565,44 @@ class ContinuousBatchingScheduler:
             # step's input buffer — this is the tick's single sync point
             toks = np.asarray(jax.device_get(self.engine.select_tokens(  # reprolint: allow[RL002] once-per-tick token drain
                 logits[:, 0], params, keys))).astype(np.int32)
+            t_emit = now_ns()
             for t, req in enumerate(self.slots):
                 if req is None or not active[t]:
                     continue
                 self._append(req, int(toks[t]), events)
                 self._next[t, 0] = toks[t]
-        self._obs_tick(t0, t_adm0, t_adm1, admitted, warming, decoded)
+        self._obs_tick((t0, t_adm1, t_sel, t_emit), admitted, warming,
+                       decoded)
         if self._debug_invariants and self.engine.kv_pool is not None:
             self.engine.kv_pool.check_invariants()
         return finished, events
 
     # -- trace drain helpers (the ONLY emission sites; see RL007) ----------
-    def _obs_tick(self, t0: int, t_adm0: int, t_adm1: int, admitted: int,
+    def _obs_tick(self, marks: Tuple[int, int, int, int], admitted: int,
                   warming: int, decoded: int) -> None:
         """Sanctioned drain point: the tick's step-phase spans, emitted
         after the tick's token drain from plain clock readings the tick
-        collected along the way (reading the clock is not emission)."""
+        collected along the way (reading the clock is not emission).
+
+        ``marks`` = (tick start, end of the retire/admit bookkeeping,
+        start of token selection, start of emission). Leaves: ``admission``
+        — retire, admit, advance prefills, on every tick (``tick``'s args
+        say whether it did admission work; on a tick that decodes nothing
+        it runs to the tick's end); on decoding ticks ``select`` — the
+        selection program and its token drain — and ``emit`` — the
+        per-token appends and callbacks. With the engine's leaves they
+        tile the host's time between two decode steps."""
+        t0, t_adm1, t_sel, t_emit = marks
         t1 = now_ns()
         self.obs.complete("sched", "tick", t0, t1,
                           {"admitted": admitted, "warming": warming,
                            "decoded": decoded,
                            "queued": len(self.queue)})
-        if admitted or warming:
-            self.obs.complete("sched", "admission", t_adm0, t_adm1)
+        self.obs.complete("sched", "admission", t0, t_adm1 if decoded else t1)
         if decoded:
             self.obs.complete("sched", "decode+drain", t_adm1, t1)
+            self.obs.complete("sched", "select", t_sel, t_emit)
+            self.obs.complete("sched", "emit", t_emit, t1)
 
     def _obs_retire(self, reqs: Sequence[Request]) -> None:
         """Sanctioned drain point: each retired (or cancelled) request's

@@ -274,40 +274,6 @@ def test_host_compute_artifact_shape_and_cost_model(tmp_path, monkeypatch):
     assert ms_on0 == ms_off0
 
 
-def test_obs_overhead_artifact_shape(tmp_path, monkeypatch):
-    """BENCH_obs_overhead.json: the tracing-overhead artifact records a
-    RunStats whose latency-percentile channel (ttft_ms_* / tpot_ms_* /
-    stall_ms_*) is part of the pinned run schema, next to the traced /
-    untraced tok/s and overhead_pct results."""
-    importlib.import_module("benchmarks.obs_overhead")      # importable
-    monkeypatch.setattr(common, "_RESULTS", [])
-    monkeypatch.setattr(common, "_RUNS", [])
-    common.emit("obs_overhead.tok_s.untraced", 120.0, "median tok/s")
-    common.emit("obs_overhead.tok_s.traced", 118.0, "median tok/s")
-    common.emit("obs_overhead.overhead_pct", 1.7, "bound 5%")
-    common.record_run("obs_overhead.traced",
-                      RunStats(engine=SAMPLE, requests_submitted=5,
-                               requests_finished=5, ttft_ms_p50=12.5,
-                               ttft_ms_p99=20.0, tpot_ms_p50=3.0,
-                               tpot_ms_p99=6.5, stall_ms_p50=0.4,
-                               stall_ms_p99=2.0))
-    path = tmp_path / "BENCH_obs_overhead.json"
-    common.dump_json(str(path))
-    doc = json.loads(path.read_text())
-    (run,) = doc["runs"]
-    assert run["name"] == "obs_overhead.traced"
-    stats = run["stats"]
-    assert set(stats) == RUN_KEYS
-    assert {"ttft_ms_p50", "ttft_ms_p95", "ttft_ms_p99",
-            "tpot_ms_p50", "tpot_ms_p95", "tpot_ms_p99",
-            "stall_ms_p50", "stall_ms_p95", "stall_ms_p99"} <= set(stats)
-    assert stats["ttft_ms_p50"] == pytest.approx(12.5)
-    assert stats["tpot_ms_p95"] == 0.0          # unset percentiles default
-    assert set(stats["engine"]) == ENGINE_KEYS
-    # the executor pool-utilization channel rides in the engine export
-    assert {"host_busy_us", "host_queue_peak"} <= set(stats["engine"])
-
-
 # -- reprolint CI artifacts: REPROLINT.json / REPROLINT.sarif ----------------
 
 REPROLINT_FIXTURE = (pathlib.Path(__file__).resolve().parent

@@ -155,6 +155,34 @@ def test_histogram_percentiles_ordered():
 # traced serving: bit-identity + trace completeness
 # ---------------------------------------------------------------------------
 
+# the host's phases of a tick: leaves of sched/tick and engine/decode_step
+LEAVES = {("engine", n) for n in ("plan", "dispatch", "commit", "wait",
+                                  "drain")} \
+    | {("sched", n) for n in ("admission", "select", "emit")}
+
+
+def _end(ev):
+    return ev.ts_ns + ev.dur_ns
+
+
+def _between_steps(rec):
+    """(a, b, leaves inside [a, b]) for every pair of consecutive decode
+    steps: a = the end of step n's ``wait``, b = the end of step n+1's
+    ``dispatch``."""
+    spans = [ev for ev in rec.events() if ev.kind == "X"]
+    leaves = sorted((ev for ev in spans if (ev.track, ev.name) in LEAVES),
+                    key=lambda ev: (ev.ts_ns, _end(ev)))
+    waits = [ev for ev in leaves if ev.name == "wait"]
+    disps = [ev for ev in leaves if ev.name == "dispatch"]
+    assert len(waits) == len(disps) > 1
+    out = []
+    for w, d in zip(waits, disps[1:]):
+        a, b = _end(w), _end(d)
+        out.append((a, b, [ev for ev in leaves
+                           if ev.ts_ns >= a and _end(ev) <= b]))
+    return out
+
+
 @pytest.mark.parametrize("mode", ["dense", "paged_segment"])
 def test_traced_serving_bit_identical_and_covered(setup, tmp_path, mode):
     cfg, params = setup
@@ -175,6 +203,16 @@ def test_traced_serving_bit_identical_and_covered(setup, tmp_path, mode):
     assert len(cover) == 3
     for track, spans in cover.items():
         assert {"queued", "prefill", "decode"} <= spans, (track, spans)
+
+    # the host's leaves between two decode steps never overlap, start
+    # where the device finished and end where the next step left
+    for a, b, inside in _between_steps(rec):
+        assert inside[0].name == "drain" and inside[0].ts_ns == a
+        assert inside[-1].name == "dispatch" and _end(inside[-1]) == b
+        assert {"select", "emit", "admission", "plan"} <= \
+            {ev.name for ev in inside}
+        for prev, nxt in zip(inside, inside[1:]):
+            assert _end(prev) <= nxt.ts_ns, (prev.name, nxt.name)
 
     # percentile channel populated on RunStats
     assert stats.ttft_ms_p50 > 0.0
@@ -203,10 +241,36 @@ def test_trace_orders_step_phases_within_tick(setup):
             assert any(t.ts_ns <= ev.ts_ns
                        and ev.ts_ns + ev.dur_ns <= t.ts_ns + t.dur_ns + 1
                        for t in ticks), ev.name
-    # engine decode steps carry lane attribution counters at the drain
+    # engine decode steps carry their lane attribution as args
     eng = [ev for ev in by_track.get("engine", []) if ev.name == "decode_step"]
     assert eng and all(ev.kind == "X" for ev in eng)
-    assert "lane:gpu" in by_track or "lane:cpu" in by_track
+    for ev in eng:
+        assert {"hit_experts", "fetched_experts", "cpu_expert_calls"} \
+            <= set(ev.args)
+    assert sum(ev.args["hit_experts"] for ev in eng) > 0
+    assert sum(ev.args["tokens"] for ev in eng) == 3 * 4   # 5 - first
+
+
+def test_leaf_spans_tile_the_host_time_between_steps(setup, monkeypatch):
+    """On a clock that advances one microsecond per reading, consecutive
+    leaves between two decode steps are one reading apart: every phase
+    of the loop that reads the clock lies inside a leaf."""
+    from repro.serving import engine as engine_mod
+    from repro.serving import scheduler as scheduler_mod
+    cfg, params = setup
+    ticks = iter(range(10 ** 9))
+    clock = lambda: 1000 * next(ticks)                    # noqa: E731
+    monkeypatch.setattr(engine_mod, "now_ns", clock)
+    monkeypatch.setattr(scheduler_mod, "now_ns", clock)
+    rec = TraceRecorder()
+    _serve(cfg, params, recorder=rec, kv_paged=True, page_size=4,
+           prefill_segment=4, admit_chunks_per_tick=1)
+    for a, b, inside in _between_steps(rec):
+        assert inside[0].ts_ns == a and _end(inside[-1]) == b
+        holes = [nxt.ts_ns - _end(prev)
+                 for prev, nxt in zip(inside, inside[1:])]
+        assert all(0 <= h <= 1000 for h in holes), \
+            [(ev.name, ev.ts_ns, _end(ev)) for ev in inside]
 
 
 def test_trace_validator_flags_malformed_documents():

@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU compiler library,
 and under xdist only the worker that runs this file does.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,3 +88,39 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     fn, specs = CASES[name]
     compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
+    """The engine's decode step at reduced widths, compiled for the chip
+    with its Pallas kernels: every stage scope reaches some instruction's
+    ``op_name``, and the grouped expert kernels keep their instruction
+    name ``moe_ffn`` (the benchmark's ``gmm_roofline`` reads it)."""
+    import dataclasses
+    from pathlib import Path
+
+    from benchmarks.chip import scopes, spec
+    from repro.config import get_config, reduced
+    from repro.kernels.decode_attention import ops as attn_ops
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    from repro.kernels.prefill_attention import ops as prefill_ops
+
+    for mod in (attn_ops, gmm_ops, prefill_ops):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    jax.clear_caches()          # no interpret-mode trace of moe_ffn reused
+    cell = spec.load_cell(Path(__file__).resolve().parents[1],
+                          "mixtral.single-decode")
+    engine = dict(cell.config["engine"], prefetch=True)
+    cell = dataclasses.replace(cell, config={**cell.config,
+                                             "engine": engine})
+    model = reduced(get_config("mixtral-8x7b"))
+    text = scopes.decode_lowered(model, cell, one_chip).compile().as_text()
+    jax.clear_caches()
+    table = scopes.parse_hlo(text)
+    own = {scopes.scope_of(op)
+           for op in re.findall(r'op_name="([^"]*)"', text)}
+    assert set(scopes.SCOPES) <= own
+    kernels = {name for name, (opcode, scope, _) in table.items()
+               if opcode == "custom-call" and scope == "moe_experts"}
+    assert kernels and {re.sub(r"\.\d+$", "", n) for n in kernels} \
+        == {"moe_ffn"}
+    assert "tpu_custom_call" in text
