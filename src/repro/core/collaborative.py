@@ -8,16 +8,18 @@ decomposed into composable stages:
   execute  — *grouped*: the assignments run through an [G, C, D] dispatch
              buffer and the grouped Pallas kernels
              (repro.kernels.moe_gmm.ops.moe_ffn). Each unique expert's
-             weights are gathered ONCE per step — resident experts from
-             the *device tier* (the [N*M, ...] cache slot buffer in fast
-             memory), non-resident experts from the *host tier* (full
-             expert table, host memory space on real hardware).
+             weights are read ONCE per step, from one tier — resident
+             experts from the *device tier* (the [N*M, ...] cache slot
+             buffer in fast memory), non-resident experts from the *host
+             tier* (full expert table, host memory space on real
+             hardware) — into one [G, ...] buffer per matrix that feeds
+             both the kernels and the commit.
   commit   — state update + post-fetch: newly inserted experts' weights
-             are written into their assigned cache slots, once per unique
-             expert. The write feeds only *future* steps (no data path to
-             this layer's output), so XLA overlaps the copy with
-             downstream compute — the TPU analogue of the paper's second
-             copy engine / dual CUDA streams.
+             are copied from that buffer into their assigned cache slots,
+             once per unique expert. The write feeds only *future* steps
+             (no data path to this layer's output), so XLA overlaps the
+             copy with downstream compute — the TPU analogue of the
+             paper's second copy engine / dual CUDA streams.
   prefetch — speculative cross-layer pre-fetch (DAOP / Pre-gated style):
              reserve slots for the experts the *next* layer's router is
              predicted to pick and stream their weights in ahead of the
@@ -29,6 +31,13 @@ decomposed into composable stages:
 :func:`collaborative_moe` is the probe→execute→commit composition (no
 prefetch); the serving engine drives the stages directly so it can overlap
 the prefetch for layer *l+1* with layer *l*'s commit.
+
+Every move of an expert's weights — the gather, the post-fetch and the
+prefetch — copies whole [D, F] (or [F, D]) matrices, one contiguous slice
+each, under a ``lax.cond`` per group (:func:`_move`): the move reads one
+tier, and only when it happens. An XLA gather or scatter over the expert
+axis is lowered into tiles and loops that run far below the chip's
+memory bandwidth at published widths.
 
 Each stage runs under a ``jax.named_scope`` — ``moe_probe``,
 ``moe_gather`` (the per-unique-expert weight gather), ``moe_dispatch``
@@ -85,6 +94,14 @@ class ExpertTiers(NamedTuple):
     slot_w3: jax.Array
     slot_w2: jax.Array     # [N*M, F, D]
     state: cache_lib.CacheState
+
+    @property
+    def tables(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        return self.host_w1, self.host_w3, self.host_w2
+
+    @property
+    def slots(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        return self.slot_w1, self.slot_w3, self.slot_w2
 
 
 def memory_kinds() -> Tuple[Optional[str], str]:
@@ -246,28 +263,53 @@ def probe(tiers: ExpertTiers, layer: jax.Array, top_i: jax.Array,
                        rep_e=rep_e, resident=resident, res_way=res_way)
 
 
+@jax.jit
+def _move(dst: Tuple[jax.Array, ...], i, src: Optional[Tuple[jax.Array, ...]],
+          j: Tuple, pred) -> Tuple[jax.Array, ...]:
+    """``d[i] = s[j]`` for each matrix pair of ``dst`` and ``src`` where
+    ``pred``, else ``dst`` unchanged; ``src`` None writes zeros.
+
+    Whole matrices move: ``j`` indexes each source's leading axes, ``i``
+    each destination's first, and the trailing two axes are copied whole
+    by a ``dynamic_slice`` and a ``dynamic_update_slice`` — contiguous, at
+    the memory's bandwidth. The ``lax.cond`` reads ``src`` only when the
+    move happens (a select would read it always) and updates ``dst`` in
+    place. Jitted so that un-jitted callers compile each shape's cond
+    once."""
+    def row(d, s):
+        if s is None:
+            return jnp.zeros((1,) + d.shape[1:], d.dtype)
+        return jax.lax.dynamic_slice(
+            s, (*j, 0, 0), (1,) * len(j) + s.shape[len(j):]
+        ).reshape((1,) + s.shape[len(j):])
+
+    def put(ds):
+        return tuple(jax.lax.dynamic_update_slice(d, row(d, s), (i, 0, 0))
+                     for d, s in zip(ds, src or (None,) * len(ds)))
+    return jax.lax.cond(pred, put, lambda ds: ds, tuple(dst))
+
+
 @jax.named_scope("moe_gather")
 def _gather_group_weights(tiers: ExpertTiers, layer, pr: ProbeResult,
                           ccfg: CacheConfig):
-    """Gather each unique expert's weights once — resident experts from the
-    slot buffer (fast tier), others from the host table (slow tier).
-
-    A set holds at most ``num_ways`` experts, so at most that many groups
-    are resident: their slot rows are written over the host gather,
-    instead of gathering all G groups from both tiers and selecting.
-    At Mixtral widths and G = 8 that keeps one [G, D, F] gather per
-    matrix off the decode step's HBM peak (~2.5 GB)."""
+    """Each unique expert's weights, read once and from one tier: a
+    resident group's from its slot (fast tier), any other group's from the
+    host table (slow tier), one ``_move`` each; padded groups
+    (``rep_e < 0``) read nothing and are zeroed, so that their rows
+    compute zeros. Every row is written exactly once, so the buffers start
+    uninitialized (``lax.empty``): no fill. Returns one [G, D, F] /
+    [G, F, D] buffer per matrix: the kernels' operand and the post-fetch's
+    source (a resident slot holds its table row's values)."""
     G = pr.rep_e.shape[0]
-    e_ix = jnp.maximum(pr.rep_e, 0)
-    host_w = (tiers.host_w1[layer, e_ix], tiers.host_w3[layer, e_ix],
-              tiers.host_w2[layer, e_ix])
-    res_g = jnp.nonzero(pr.resident, size=min(ccfg.num_ways, G),
-                        fill_value=G)[0]             # G = none: dropped
-    way = pr.res_way[jnp.minimum(res_g, G - 1)]
-    slots = cache_lib.slot_id(layer, jnp.maximum(way, 0), ccfg.num_ways)
-    w = tuple(h.at[res_g].set(s[slots], mode="drop") for h, s in
-              zip(host_w, (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2)))
-    return w, host_w
+    slots = cache_lib.slot_id(layer, pr.res_way, ccfg.num_ways)
+    from_table = ~pr.resident & (pr.rep_e >= 0)
+    w = tuple(jax.lax.empty((G,) + t.shape[2:], t.dtype)
+              for t in tiers.tables)
+    for g in range(G):
+        w = _move(w, g, tiers.slots, (slots[g],), pr.resident[g])
+        w = _move(w, g, tiers.tables, (layer, pr.rep_e[g]), from_table[g])
+        w = _move(w, g, None, (), pr.rep_e[g] < 0)
+    return w
 
 
 @jax.named_scope("moe_dispatch")
@@ -299,26 +341,26 @@ def execute(tiers: ExpertTiers, layer: jax.Array, x: jax.Array,
             ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array, jax.Array]]:
     """Stage 2 — grouped tiered execution through the gmm kernels.
 
-    Returns (y [T, D], host-tier gathers of the step's unique experts —
-    reused by commit()'s post-fetch so each expert's host read happens
-    once per step)."""
+    Returns (y [T, D], the step's unique experts' weights — reused by
+    commit()'s post-fetch so each expert's weights are read once per
+    step)."""
     T, K = top_w.shape
     tok, xbuf = _stage_dispatch(x, K, pr)
-    w, host_w = _gather_group_weights(tiers, layer, pr, ccfg)
+    w = _gather_group_weights(tiers, layer, pr, ccfg)
     ybuf = experts(xbuf, w)                                # [G, A, D]
     y = _combine(ybuf, pr.gid, pr.pos, tok, top_w, pr.valid, T, x.dtype)
-    return y, host_w
+    return y, w
 
 
 @jax.named_scope("moe_commit")
-def commit(tiers: ExpertTiers, layer: jax.Array, pr: ProbeResult, host_w,
+def commit(tiers: ExpertTiers, layer: jax.Array, pr: ProbeResult, w,
            ccfg: CacheConfig) -> Tuple[ExpertTiers, jax.Array]:
     """Stage 3 — install the probe's cache state and post-fetch the newly
-    inserted experts' weights into their slots (async-schedulable: no data
-    path back to this layer's output). Returns (tiers, fetch [G] bool)."""
+    inserted experts' weights ``w`` (execute()'s [G, ...] buffers) into
+    their slots (async-schedulable: no data path back to this layer's
+    output). Returns (tiers, fetch [G] bool)."""
     s_w1, s_w3, s_w2, fetch = _post_fetch(
-        tiers, layer, pr.rep_e, pr.resident, pr.res_way, pr.state, host_w,
-        ccfg)
+        tiers, layer, pr.rep_e, pr.resident, pr.res_way, pr.state, w, ccfg)
     tiers = tiers._replace(slot_w1=s_w1, slot_w3=s_w3, slot_w2=s_w2,
                            state=pr.state)
     return tiers, fetch
@@ -384,37 +426,33 @@ def prefetch(tiers: ExpertTiers, layer: jax.Array, pred_i: jax.Array,
         jnp.where(issued_a, ways_a, 0))
     # stream the issued experts' weights into the reserved slots (the
     # speculative transfer the in-flight flag models; next probe lands it)
-    e_ix = jnp.maximum(rep_p, 0)
-    S = tiers.slot_w1.shape[0]
     dst = cache_lib.slot_id(layer, way, ccfg.num_ways)
-    dst = jnp.where(issued, dst, S)    # out-of-range + drop = no write
-    s_w1 = tiers.slot_w1.at[dst].set(tiers.host_w1[layer, e_ix], mode="drop")
-    s_w3 = tiers.slot_w3.at[dst].set(tiers.host_w3[layer, e_ix], mode="drop")
-    s_w2 = tiers.slot_w2.at[dst].set(tiers.host_w2[layer, e_ix], mode="drop")
-    tiers = tiers._replace(slot_w1=s_w1, slot_w3=s_w3, slot_w2=s_w2,
-                           state=new_state)
+    slots = tiers.slots
+    for g in range(G):
+        slots = _move(slots, dst[g], tiers.tables, (layer, rep_p[g]),
+                      issued[g])
+    tiers = tiers._replace(slot_w1=slots[0], slot_w3=slots[1],
+                           slot_w2=slots[2], state=new_state)
     return tiers, rep_p, issued, issued_a.sum()
 
 
 def _post_fetch(tiers: ExpertTiers, layer, rep_e, resident, res_way,
-                new_state, host_w, ccfg: CacheConfig):
-    """Write inserted experts' weights into their slots, once per unique
-    expert. Probes the POST-step state: an expert is fetched iff its final
-    (expert -> way) mapping is not already backed by the buffer — newly
-    resident, or evicted-and-reinserted at a different way within the step
-    (possible when picks exceed the ways). An expert inserted then evicted
-    within the same step is correctly skipped. Output `y` never reads
-    these writes."""
+                new_state, w, ccfg: CacheConfig):
+    """Write inserted experts' weights (group g's row of each buffer in
+    ``w``) into their slots, once per unique expert. Probes the POST-step
+    state: an expert is fetched iff its final (expert -> way) mapping is
+    not already backed by the buffer — newly resident, or evicted-and-
+    reinserted at a different way within the step (possible when picks
+    exceed the ways). An expert inserted then evicted within the same step
+    is correctly skipped. Fetched experts hold distinct ways, so the
+    writes never overlap. Output `y` never reads these writes."""
     new_res, new_way = cache_lib.lookup(new_state, layer, rep_e)
     fetch = new_res & ~(resident & (new_way == res_way))
     dst = cache_lib.slot_id(layer, new_way, ccfg.num_ways)
-    # out-of-range destination + mode="drop" suppresses non-fetched rows
-    dst = jnp.where(fetch, dst, tiers.slot_w1.shape[0])
-    host_w1, host_w3, host_w2 = host_w
-    s_w1 = tiers.slot_w1.at[dst].set(host_w1, mode="drop")
-    s_w3 = tiers.slot_w3.at[dst].set(host_w3, mode="drop")
-    s_w2 = tiers.slot_w2.at[dst].set(host_w2, mode="drop")
-    return s_w1, s_w3, s_w2, fetch
+    slots = tiers.slots
+    for g in range(rep_e.shape[0]):
+        slots = _move(slots, dst[g], w, (g,), fetch[g])
+    return (*slots, fetch)
 
 
 @jax.named_scope("moe_dispatch")
@@ -450,8 +488,8 @@ def collaborative_moe(tiers: ExpertTiers, layer: jax.Array, x: jax.Array,
     Returns (y [T, D], updated tiers, stats).
     """
     pr = probe(tiers, layer, top_i, ccfg, active=active)
-    y, host_w = execute(tiers, layer, x, top_w, pr, ccfg)
-    tiers, fetch = commit(tiers, layer, pr, host_w, ccfg)
+    y, w = execute(tiers, layer, x, top_w, pr, ccfg)
+    tiers, fetch = commit(tiers, layer, pr, w, ccfg)
     return y, tiers, _stats(pr, fetch)
 
 

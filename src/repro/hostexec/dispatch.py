@@ -83,16 +83,16 @@ def dispatch_execute(tiers: collab.ExpertTiers, layer: jax.Array,
     Same signature contract as :func:`repro.core.collaborative.execute`
     plus the split table and (for the callback backend) the executor;
     ``fuse_small`` is the executor's small-group fusion threshold (the
-    stat mirrors it for both backends); returns (y [T, D], host-tier
-    gathers for commit()'s post-fetch, dispatch stats
+    stat mirrors it for both backends); returns (y [T, D], the step's
+    expert weights for commit()'s post-fetch, dispatch stats
     {cpu_expert_calls, cpu_tokens, miss_expert_groups, fused_groups})."""
     T, K = top_w.shape
     tok, xbuf = collab._stage_dispatch(x, K, pr)
-    w, host_w = collab._gather_group_weights(tiers, layer, pr, ccfg)
+    w = collab._gather_group_weights(tiers, layer, pr, ccfg)
     to_cpu, counts = dispatch_plan(pr, cpu_table)
 
     # device lane: grouped gmm over the tiered gather (hit groups read the
-    # slot buffer, fetch-set misses the host tier — unchanged)
+    # slot buffer, fetch-set misses the host tier)
     ybuf_dev = collab.experts(xbuf, w)                     # [G, A, D]
 
     if executor is not None:
@@ -131,4 +131,4 @@ def dispatch_execute(tiers: collab.ExpertTiers, layer: jax.Array,
             (to_cpu & (counts <= fuse_small)).sum().astype(jnp.int32)
             if fuse_small > 0 else jnp.int32(0)),
     }
-    return y, host_w, dstats
+    return y, w, dstats

@@ -475,18 +475,18 @@ class CollaborativeEngine:
                 # hybrid dispatcher (repro.hostexec): GPU-hit groups run
                 # the grouped kernels, CPU-miss groups the host executor,
                 # cost-model-chosen; cache warming identical either way
-                y, host_w, dstats = self._dispatch_execute(
+                y, w, dstats = self._dispatch_execute(
                     tiers, layer, h2[:, 0], top_w, pr, ccfg,
                     self._cpu_table, self.host_executor,
                     self.ecfg.host_fuse_small)
             else:
-                y, host_w = collab.execute(tiers, layer, h2[:, 0], top_w,
-                                           pr, ccfg)
+                y, w = collab.execute(tiers, layer, h2[:, 0], top_w, pr,
+                                      ccfg)
                 dstats = {"cpu_expert_calls": jnp.zeros((), jnp.int32),
                           "cpu_tokens": jnp.zeros((), jnp.int32),
                           "miss_expert_groups": jnp.zeros((), jnp.int32),
                           "fused_groups": jnp.zeros((), jnp.int32)}
-            tiers, fetch = collab.commit(tiers, layer, pr, host_w, ccfg)
+            tiers, fetch = collab.commit(tiers, layer, pr, w, ccfg)
             x = x + y[:, None].astype(x.dtype)
 
             if self.ecfg.prefetch:
@@ -837,9 +837,9 @@ class CollaborativeEngine:
         def body(carry, xs):
             tiers, layer = carry
             pr = collab.probe(tiers, layer, xs["top_i"], ccfg, active=active)
-            _, host_w = collab.execute(tiers, layer, xs["h2"], xs["top_w"],
-                                       pr, ccfg)
-            tiers, fetch = collab.commit(tiers, layer, pr, host_w, ccfg)
+            _, w = collab.execute(tiers, layer, xs["h2"], xs["top_w"], pr,
+                                  ccfg)
+            tiers, fetch = collab.commit(tiers, layer, pr, w, ccfg)
             return (tiers, layer + 1), collab._stats(pr, fetch)
 
         (tiers, _), stats = jax.lax.scan(

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.config import CacheConfig
+from repro.core import cache as cache_lib
 from repro.core import collaborative as collab
 from repro.core.cache import init_cache_state
 
@@ -209,3 +210,147 @@ def test_static_random_preload():
             np.testing.assert_array_equal(
                 np.asarray(tiers.slot_w1[l * 2 + w]),
                 np.asarray(tiers.host_w1[l, e]))
+
+
+# ---------------------------------------------------------------------------
+# slice moves vs the gather/scatter formulation they replaced
+# ---------------------------------------------------------------------------
+
+def _oracle_moe(tiers, layer, x, top_i, top_w, ccfg, active=None):
+    """One layer through the expert cache as XLA gathers and scatters over
+    the expert axis: the host table gathered for every group, resident
+    slot rows scattered over it, and the post-fetch scattered from the
+    host gather. The parity oracle of the slice moves."""
+    pr = collab.probe(tiers, layer, top_i, ccfg, active=active)
+    T, K = top_w.shape
+    G = pr.rep_e.shape[0]
+    slot_bufs = (tiers.slot_w1, tiers.slot_w3, tiers.slot_w2)
+    e_ix = jnp.maximum(pr.rep_e, 0)
+    host_w = (tiers.host_w1[layer, e_ix], tiers.host_w3[layer, e_ix],
+              tiers.host_w2[layer, e_ix])
+    res_g = jnp.nonzero(pr.resident, size=min(ccfg.num_ways, G),
+                        fill_value=G)[0]
+    way = pr.res_way[jnp.minimum(res_g, G - 1)]
+    slots = cache_lib.slot_id(layer, jnp.maximum(way, 0), ccfg.num_ways)
+    w = tuple(h.at[res_g].set(s[slots], mode="drop")
+              for h, s in zip(host_w, slot_bufs))
+    tok, xbuf = collab._stage_dispatch(x, K, pr)
+    y = collab._combine(collab.experts(xbuf, w), pr.gid, pr.pos, tok,
+                        top_w, pr.valid, T, x.dtype)
+    new_res, new_way = cache_lib.lookup(pr.state, layer, pr.rep_e)
+    fetch = new_res & ~(pr.resident & (new_way == pr.res_way))
+    dst = jnp.where(fetch,
+                    cache_lib.slot_id(layer, new_way, ccfg.num_ways),
+                    tiers.slot_w1.shape[0])
+    s1, s3, s2 = (s.at[dst].set(h, mode="drop")
+                  for s, h in zip(slot_bufs, host_w))
+    tiers = tiers._replace(slot_w1=s1, slot_w3=s3, slot_w2=s2,
+                           state=pr.state)
+    return y, tiers, collab._stats(pr, fetch), pr, fetch
+
+
+def _oracle_prefetch(tiers, layer, pred_i, ccfg):
+    """collab.prefetch with its slot writes as one scatter per matrix."""
+    flat_p = pred_i.reshape(-1).astype(jnp.int32)
+    state, issued_a, ways_a = cache_lib.reserve(tiers.state, layer, flat_p,
+                                                ccfg.policy)
+    gid, _, rep_p = collab._group_by_expert(flat_p, tiers.host_w1.shape[1])
+    G = rep_p.shape[0]
+    issued = jnp.zeros((G,), bool).at[gid].max(issued_a)
+    way = jnp.zeros((G,), jnp.int32).at[gid].add(
+        jnp.where(issued_a, ways_a, 0))
+    dst = jnp.where(issued, cache_lib.slot_id(layer, way, ccfg.num_ways),
+                    tiers.slot_w1.shape[0])
+    e_ix = jnp.maximum(rep_p, 0)
+    s1, s3, s2 = (s.at[dst].set(t[layer, e_ix], mode="drop")
+                  for s, t in ((tiers.slot_w1, tiers.host_w1),
+                               (tiers.slot_w3, tiers.host_w3),
+                               (tiers.slot_w2, tiers.host_w2)))
+    tiers = tiers._replace(slot_w1=s1, slot_w3=s3, slot_w2=s2, state=state)
+    return tiers, rep_p, issued, issued_a.sum()
+
+
+def _assert_tiers_equal(a, b):
+    for fa, fb in zip(a, b):
+        for xa, xb in zip(jax.tree.leaves(fa), jax.tree.leaves(fb)):
+            np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
+
+
+# (action, layer, picks, active): "moe" runs one layer, "prefetch" reserves
+# the picks for `layer`. Two ways a set, two covered layers, four experts.
+_ROW = [[0.6, 0.4], [0.5, 0.5]]
+PARITY_CASES = {
+    # expert 1 resident, 2 not; then layer 2, beyond the cache's coverage
+    "resident_and_not": [("moe", 0, [[0, 1]], None),
+                         ("moe", 0, [[1, 2]], None),
+                         ("moe", 2, [[1, 3]], None)],
+    # expert 0 sits in way 0, is evicted by 2 and 3 and comes back in
+    # way 1 within one step: its slot row moves to the other way
+    "remapped_way": [("moe", 0, [[0, 1]], None),
+                     ("moe", 0, [[1, 0]], None),
+                     ("moe", 0, [[2, 3], [0, 0]], None)],
+    # two tokens pick the same expert, cold and then resident
+    "duplicate_picks": [("moe", 1, [[0, 1], [0, 2]], None),
+                        ("moe", 1, [[0, 2], [2, 0]], None)],
+    # row 1 masked: its assignments form a group of expert -1, and one
+    # more group is padding
+    "padded_group": [("moe", 0, [[0, 1], [2, 3]], [True, False]),
+                     ("moe", 0, [[1, 3], [0, 0]], [True, False])],
+    # reservations stream weights in; the next probe lands and reads them
+    "prefetch": [("moe", 0, [[0, 1]], None),
+                 ("prefetch", 1, [[2, 3]], None),
+                 ("moe", 1, [[3, 1]], None),
+                 ("prefetch", 0, [[2, 1], [3, 3]], None),
+                 ("moe", 0, [[2, 0]], None)],
+}
+
+
+@pytest.mark.parametrize("case", list(PARITY_CASES))
+def test_slice_moves_match_gather_scatter_formulation(case, monkeypatch):
+    """execute, commit and prefetch move each expert's weights as whole
+    contiguous slices under a per-group cond; they give the same y (bit for
+    bit), slot buffers, cache state and stats as the XLA gather/scatter
+    formulation they replaced, and each case reaches what it names. The
+    gathered buffers start as NaN here, as uninitialized memory may read
+    on the chip, so a row no move writes shows in y."""
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype, **_:
+                        jnp.full(shape, jnp.nan, dtype))
+    key = jax.random.PRNGKey(13)
+    tiers, ccfg = _tiers(key)
+    ref = tiers
+    x2 = jax.random.normal(key, (2, 16), jnp.float32)
+    seen = set()
+    for action, layer, picks, active in PARITY_CASES[case]:
+        ti = jnp.asarray(picks, jnp.int32)
+        if action == "prefetch":
+            tiers, *got = collab.prefetch(tiers, jnp.int32(layer), ti, ccfg)
+            ref, *want = _oracle_prefetch(ref, jnp.int32(layer), ti, ccfg)
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            seen.add("issued" if np.asarray(got[1]).any() else "")
+        else:
+            x = x2[:ti.shape[0]]
+            tw = jnp.asarray(_ROW[:ti.shape[0]], jnp.float32)
+            act = None if active is None else jnp.asarray(active)
+            y, tiers, stats = collab.collaborative_moe(
+                tiers, jnp.int32(layer), x, ti, tw, ccfg, active=act)
+            y_ref, ref, stats_ref, pr, fetch = _oracle_moe(
+                ref, jnp.int32(layer), x, ti, tw, ccfg, active=act)
+            assert np.isfinite(np.asarray(y)).all()
+            np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
+            assert {k: int(v) for k, v in stats.items()} == \
+                {k: int(v) for k, v in stats_ref.items()}
+            res = np.asarray(pr.resident)
+            rep_e = np.asarray(pr.rep_e)
+            if res.any() and (~res & (rep_e >= 0)).any():
+                seen.add("resident_and_not")
+            if (res & np.asarray(fetch)).any():
+                seen.add("remapped_way")
+            if len(set(np.asarray(pr.flat_e).tolist())) < pr.flat_e.size:
+                seen.add("duplicate_picks")
+            if (rep_e < 0).sum() >= 2:
+                seen.add("padded_group")
+            if int(stats["prefetch_hits"]) > 0:
+                seen.add("prefetch")
+        _assert_tiers_equal(tiers, ref)
+    assert case in seen, (case, seen)

@@ -90,11 +90,12 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
-    """The engine's decode step at reduced widths, compiled for the chip
-    with its Pallas kernels: every stage scope reaches some instruction's
-    ``op_name``, and the grouped expert kernels keep their instruction
-    name ``moe_ffn`` (the benchmark's ``gmm_roofline`` reads it)."""
+def _decode_step_text(one_chip, monkeypatch, max_batch=None,
+                      num_experts=None) -> str:
+    """The compiled text of the engine's decode step at reduced widths, for
+    the chip with its Pallas kernels: the ``mixtral.single-decode`` cell's
+    engine with prefetch on, optionally at another slot count and expert
+    count."""
     import dataclasses
     from pathlib import Path
 
@@ -110,11 +111,27 @@ def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
     cell = spec.load_cell(Path(__file__).resolve().parents[1],
                           "mixtral.single-decode")
     engine = dict(cell.config["engine"], prefetch=True)
+    model = reduced(get_config("mixtral-8x7b"))
+    if max_batch is not None:
+        engine["max_batch"] = max_batch
+    if num_experts is not None:
+        model = dataclasses.replace(model, moe=dataclasses.replace(
+            model.moe, num_experts=num_experts))
     cell = dataclasses.replace(cell, config={**cell.config,
                                              "engine": engine})
-    model = reduced(get_config("mixtral-8x7b"))
     text = scopes.decode_lowered(model, cell, one_chip).compile().as_text()
     jax.clear_caches()
+    return text
+
+
+def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
+    """The engine's decode step at reduced widths, compiled for the chip
+    with its Pallas kernels: every stage scope reaches some instruction's
+    ``op_name``, and the grouped expert kernels keep their instruction
+    name ``moe_ffn`` (the benchmark's ``gmm_roofline`` reads it)."""
+    from benchmarks.chip import scopes
+
+    text = _decode_step_text(one_chip, monkeypatch)
     table = scopes.parse_hlo(text)
     own = {scopes.scope_of(op)
            for op in re.findall(r'op_name="([^"]*)"', text)}
@@ -124,3 +141,31 @@ def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
     assert kernels and {re.sub(r"\.\d+$", "", n) for n in kernels} \
         == {"moe_ffn"}
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("max_batch,num_experts", [(None, None), (8, 16)],
+                         ids=["cell", "batch8_experts16"])
+def test_decode_step_moves_expert_weights_as_slices_for_v5e(
+        one_chip, monkeypatch, max_batch, num_experts):
+    """The expert cache moves weights as whole contiguous slices: no gather
+    or scatter of floating-point values (expert weights) resolves to
+    ``moe_gather``, ``moe_commit`` or ``moe_prefetch``, at the cell's G = 2
+    groups and at 8 slots over 16 experts (G = 16). The cache's integer
+    bookkeeping (tags, ways, votes) may still gather and scatter; the
+    stage scopes are all still found."""
+    from benchmarks.chip import scopes
+
+    text = _decode_step_text(one_chip, monkeypatch, max_batch, num_experts)
+    dtype = dict(re.findall(
+        r"^\s+(?:ROOT )?%(\S+) = \(?([a-z]+[0-9]*)\[", text, re.M))
+    table = scopes.parse_hlo(text)
+    assert set(scopes.SCOPES) <= {scope for _, scope, _ in table.values()}
+    moves = {name: (opcode, scope, dtype.get(name))
+             for name, (opcode, scope, _) in table.items()
+             if opcode in ("gather", "scatter")
+             and scope in ("moe_gather", "moe_commit", "moe_prefetch")
+             and dtype.get(name, "")[:1] in ("f", "b")}
+    assert not moves, moves
+    slices = {scope for _, (opcode, scope, _) in table.items()
+              if opcode == "dynamic-update-slice"}
+    assert {"moe_gather", "moe_commit", "moe_prefetch"} <= slices
