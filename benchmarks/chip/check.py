@@ -2,12 +2,14 @@
 
 After the window closes and the program's state is freed, a sample of the
 requests that were served tokens — drawn from the seed, always with the
-one that was served the most — is run through the float32 reference
-(``reference.py``) over its prompt followed by the tokens the timed path
-served. At each served token the reference's best logit minus its logit
-for the served token is that token's gap. The number compared is
-``mismatch_share``: the share of served tokens whose gap is above 0, that
-is, tokens that are not the reference's first choice. Serving is greedy,
+one that was served the most — is run through the float32 reference of
+the configuration's ``model_type`` (``references/<model_type>.py``, fed
+the configuration's published keys) over its prompt followed by the
+tokens the timed path served. At each served token the reference's best
+logit minus its logit for the served token is that token's gap. The
+number compared is ``mismatch_share``: the share of served tokens whose
+gap is above 0, that is, tokens that are not the reference's first
+choice. Serving is greedy,
 so a sound program serves the reference's first choice except where
 bfloat16 rounding flips a near tie (of the best logits, or of the
 router's top-k, after which the sequence goes on from another token).
@@ -52,22 +54,22 @@ def served_gaps(ref_logits: np.ndarray, prompt_len: int,
     return best - rows[np.arange(len(served)), np.asarray(served)]
 
 
-def compare(params, model_cfg, reqs: Sequence, length: int,
+def compare(params, reference, keys: Dict, reqs: Sequence, length: int,
             control: bool = False) -> Dict[str, float]:
-    """Runs the reference over each request. Returns, over the sample's
+    """Runs ``reference`` (a ``references/<model_type>.py`` module) with
+    the published ``keys`` over each request. Returns, over the sample's
     served tokens, the widest gap (``logit_gap``), the mean gap
     (``mean_gap``) and the share of tokens that are not the reference's
     first choice (``mismatch_share``), with the counts; with ``control``
     the same three readings of the float8 forward's first choices
     (``..._control``)."""
-    from . import reference
     gaps, ctl = [], []
     for r in reqs:
         seq = np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
-        ref = np.asarray(reference.logits(params, model_cfg, seq, length))
+        ref = np.asarray(reference.logits(params, keys, seq, length))
         gaps.append(served_gaps(ref, len(r.prompt), r.tokens))
         if control:
-            low = np.asarray(reference.logits(params, model_cfg, seq, length,
+            low = np.asarray(reference.logits(params, keys, seq, length,
                                               fp8=True))
             picks = low[len(r.prompt) - 1:].argmax(-1)
             ctl.append(served_gaps(ref, len(r.prompt), picks))

@@ -263,10 +263,11 @@ class ClosedLoop:
 
 
 def prepare(root: Path, workload: str, require_chip: bool = True):
-    """Loads the cell, points JAX's persistent compilation cache at
-    ``<checkout>/.jax_cache`` and checks the devices. Exits with code 2,
-    before any work, where JAX finds no TPU or fewer chips than the cell
-    asks for."""
+    """Loads the cell (raising, before any device work, where the program
+    or the reference cannot take its configuration: ``spec.load_cell``),
+    points JAX's persistent compilation cache at ``<checkout>/.jax_cache``
+    and checks the devices. Exits with code 2, before any work, where JAX
+    finds no TPU or fewer chips than the cell asks for."""
     cell = spec.load_cell(root, workload)
     import jax
     jax.config.update("jax_compilation_cache_dir",
@@ -299,7 +300,7 @@ def serve_window(cell: spec.Cell, jax, seed: int, seconds: float,
     state is out of scope."""
     from repro.serving import build
     device = _device_info(jax)
-    model = spec.model_config(cell.config)
+    model = cell.model
     params = weights.make_params(model, seed)
     _say(f"setup: weights {weights.nbytes(params)} B, "
          f"peak_bytes_in_use {_peak_bytes(jax)}")
@@ -387,13 +388,14 @@ def verify(cell: spec.Cell, window: Window, seed: int,
     requests that were served tokens, drawn from the seed, against the
     reference (``check.py``)."""
     gc.collect()
-    model = spec.model_config(cell.config)
     rng = np.random.default_rng(weights.seed32(seed, "check"))
     picked = check.sample([r for r in window.served if r.tokens],
                           int(cell.traffic["check_requests"]), rng)
-    params = weights.make_params(model, seed)
+    params = weights.make_params(cell.model, seed)
     t = time.perf_counter()
-    readings = check.compare(params, model, picked, cell.capacity, control)
+    readings = check.compare(params, cell.reference,
+                             spec.published(cell.config), picked,
+                             cell.capacity, control)
     _say(f"check: {readings['requests_compared']} request(s), "
          f"{readings['tokens_compared']} served token(s), "
          f"{time.perf_counter() - t:.3f} s")

@@ -3,9 +3,14 @@
 A cell names a configuration and a traffic mix. The configuration's file
 is the one its ``configs`` entry gives; the mix is
 ``benchmarks/chip/traffic/<traffic>.json``; each per-layer metric is read
-by ``benchmarks/chip/metrics/<name>.py``. Nothing here knows a cell,
-model or metric by name, so a later cell is added with files and entries
-alone.
+by ``benchmarks/chip/metrics/<name>.py``. A configuration's published
+keys (every key but the harness's own, ``HARNESS_KEYS``) become the
+program's ModelConfig through ``benchmarks/chip/hf/<model_type>.py``,
+and are run through ``benchmarks/chip/references/<model_type>.py``, the
+plain reference. Nothing here knows a cell, model type or metric by
+name, so a later cell is added with files and entries alone. A key the
+harness itself adds to configuration files goes into ``HARNESS_KEYS``,
+or it is refused as a published key that no mapping reads.
 """
 from __future__ import annotations
 
@@ -18,6 +23,10 @@ from typing import Any, Callable, Dict, List, Optional
 from .traffic import longest
 
 CHIP_DIR = "benchmarks/chip"
+# a configuration file's own keys; every other key is a published one
+HARNESS_KEYS = frozenset((
+    "name", "source", "paper", "reduced", "published", "deployment",
+    "assumed", "departures", "engine", "limits"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +36,8 @@ class Cell:
     chips: int
     config: Dict[str, Any]          # the configuration file, as run
     traffic: Dict[str, Any]         # the traffic file
+    model: Any                      # the program's ModelConfig of it
+    reference: Any                  # references/<model_type>.py
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
 
@@ -45,6 +56,10 @@ def _for_cell(metrics, cell: str) -> List[Dict[str, Any]]:
 
 
 def load_cell(root: Path, workload: str) -> Cell:
+    """The cell's files. Raises where its configuration has a published
+    key that ``hf/<model_type>.py`` neither reads nor skips, or where its
+    ``model_type`` has no mapping or no reference file: before any weights
+    are made."""
     root = Path(root)
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -58,30 +73,55 @@ def load_cell(root: Path, workload: str) -> Cell:
         (root / CHIP_DIR / "traffic" / f"{w['traffic']}.json").read_text())
     return Cell(root=root, name=workload, chips=int(w["chips"]),
                 config=config, traffic=traffic,
+                model=model_config(root, config),
+                reference=reference(root, config),
                 end_to_end=_for_cell(bench["end_to_end"], workload),
                 per_layer=_for_cell(bench["per_layer"], workload))
 
 
-def model_config(conf: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file's published
-    keys (Hugging Face ``config.json`` names)."""
-    from repro.config import ModelConfig, MoEConfig
-    heads = conf["num_attention_heads"]
-    return ModelConfig(
-        name=conf["name"], family="moe",
-        num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], num_heads=heads,
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or conf["hidden_size"] // heads,
-        d_ff=0, vocab_size=conf["vocab_size"],
-        moe=MoEConfig(num_experts=conf["num_local_experts"],
-                      top_k=conf["num_experts_per_tok"],
-                      d_ff=conf["intermediate_size"]),
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf.get("tie_word_embeddings", False)),
-        max_seq_len=conf["max_position_embeddings"],
-        dtype=conf["torch_dtype"])
+def published(conf: Dict[str, Any]) -> Dict[str, Any]:
+    """The configuration's published keys: Hugging Face ``config.json``
+    names and values, as run."""
+    return {k: v for k, v in conf.items() if k not in HARNESS_KEYS}
+
+
+def _module(root: Path, kind: str, name: str, what: str):
+    """``benchmarks/chip/<kind>/<name>.py`` of the checkout at ``root``,
+    loaded by path."""
+    path = Path(root) / CHIP_DIR / kind / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"no {what} {name!r}: "
+                         f"{CHIP_DIR}/{kind}/{name}.py is missing")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}",
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_config(root: Path, conf: Dict[str, Any]):
+    """The program's ModelConfig for a configuration file, through
+    ``hf/<model_type>.py``. Raises, naming the key and the type, where a
+    published key is neither read (mapped or checked) nor skipped there,
+    or where the program does not do what a read key says."""
+    keys = published(conf)
+    kind = keys.get("model_type")
+    hf = _module(root, "hf", str(kind), "program mapping of model_type")
+    unknown = sorted(set(keys) - hf.READ - set(hf.SKIPPED))
+    if unknown:
+        raise ValueError(f"model_type {kind!r}: published key(s) "
+                         f"{', '.join(unknown)} neither read nor skipped "
+                         f"by {CHIP_DIR}/hf/{kind}.py")
+    return hf.model_config(conf["name"], keys)
+
+
+def reference(root: Path, conf: Dict[str, Any]):
+    """The plain reference of the configuration's ``model_type``:
+    ``references/<model_type>.py``, whose ``logits(params, keys, tokens,
+    length, fp8)`` takes the published keys."""
+    return _module(root, "references", str(conf.get("model_type")),
+                   "reference of model_type")
 
 
 def engine_settings(cell: Cell) -> Dict[str, Any]:
@@ -95,13 +135,7 @@ def engine_settings(cell: Cell) -> Dict[str, Any]:
 def load_reader(root: Path, metric: str) -> Callable[[Any], Optional[float]]:
     """The per-layer metric's reader: ``metrics/<metric>.py``'s
     ``read(ctx)``."""
-    path = Path(root) / CHIP_DIR / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_').replace('-', '_')}",
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root, "metrics", metric, "reader of metric").read
 
 
 def peaks(root: Path, device_kind: str) -> Dict[str, Any]:

@@ -17,12 +17,18 @@ CHIP = ROOT / "benchmarks/chip"
 SEED = 2 ** 40 + 11                  # a seed wider than 32 bits
 
 
-def _throwaway(root: Path) -> None:
+def _throwaway(root: Path, model_type: str = "mixtral") -> None:
     """A checkout-shaped tree whose BENCHMARK.json holds one new cell, on
     a new configuration file and a new mix file, with one new metric
-    reader beside copies of the real ones."""
+    reader beside copies of the real ones. A ``model_type`` other than
+    Mixtral's comes with files of its own alone: its program mapping and
+    its reference, here copies of Mixtral's."""
     chip = root / "benchmarks/chip"
-    shutil.copytree(CHIP / "metrics", chip / "metrics")
+    for kind in ("metrics", "hf", "references"):
+        shutil.copytree(CHIP / kind, chip / kind)
+    for kind in ("hf", "references"):
+        shutil.copy(CHIP / kind / "mixtral.py",
+                    chip / kind / f"{model_type}.py")
     (chip / "metrics/tokens_seen.py").write_text(
         "def read(ctx):\n"
         "    return float(sum(len(r.tokens) for r in ctx.served))\n")
@@ -31,7 +37,7 @@ def _throwaway(root: Path) -> None:
     # best logits, which rounding has to cross, are then the real ones
     conf.update(name="tiny-moe", hidden_size=256, intermediate_size=128,
                 num_attention_heads=4, num_key_value_heads=2,
-                num_local_experts=4)
+                num_local_experts=4, model_type=model_type)
     conf["engine"].update(max_batch=2, prefill_segment=16)
     (chip / "configs").mkdir()
     (chip / "configs/tiny-moe.json").write_text(json.dumps(conf))
@@ -77,8 +83,12 @@ def _run(harness, root, trace=False):
                             t_start=time.perf_counter(), require_chip=False)
 
 
-def test_throwaway_cell_runs_end_to_end(harness, tiny):
-    out = _run(harness, tiny)
+@pytest.mark.parametrize("model_type", ["mixtral", "toy_moe"])
+def test_throwaway_cell_runs_end_to_end(harness, tmp_path, model_type):
+    _throwaway(tmp_path, model_type)
+    cell, _ = harness.prepare(tmp_path, "tiny.cell", require_chip=False)
+    assert Path(cell.reference.__file__).name == f"{model_type}.py"
+    out = _run(harness, tmp_path)
     assert out["correct"] is True, out
     assert out["failed"] == 0 and out["attempted"] > 0
     assert set(out["metrics"]) >= {"itl_ms_p95", "setup_s"}
@@ -155,7 +165,7 @@ def test_the_float8_control_is_not_correct(harness, tiny):
     import numpy as np
     from repro.serving import build
     cell, _ = harness.prepare(tiny, "tiny.cell", require_chip=False)
-    model = harness.spec.model_config(cell.config)
+    model = cell.model
     params = harness.weights.make_params(model, SEED)
     eng = harness.spec.engine_settings(cell)
     _, sched = build(model, cache=eng["cache"], serving=eng["serving"],
@@ -167,8 +177,9 @@ def test_the_float8_control_is_not_correct(harness, tiny):
         harness._submit(sched, rec, lambda r: None)
     while harness._busy(sched):
         sched.step()
-    readings = harness.check.compare(params, model, served, cell.capacity,
-                                     control=True)
+    readings = harness.check.compare(
+        params, cell.reference, harness.spec.published(cell.config), served,
+        cell.capacity, control=True)
     assert readings["tokens_compared"] == sum(r.max_new for r in served)
     for name, limit in cell.config["limits"].items():
         assert readings[name] <= limit < readings[name + "_control"], name

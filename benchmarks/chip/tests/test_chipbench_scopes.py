@@ -173,7 +173,7 @@ def test_every_reader_on_the_existing_synthetic_trace(monkeypatch):
     req = harness.Served(0, np.zeros(200, np.int32), 8)
     req.times, req.tokens = [960, 990], [1, 2]
     ctx = trace.Context(
-        cell=cell, model=spec.model_config(cell.config), served=[req],
+        cell=cell, model=cell.model, served=[req],
         t0=0, t1=1000, stats0=type("S", (), {"hits": 10, "accesses": 40}),
         stats1=type("S", (), {"hits": 40, "accesses": 100}),
         peak=spec.peaks(ROOT, "TPU v5 lite"), trace=_reduction())

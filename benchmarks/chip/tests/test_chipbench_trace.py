@@ -88,7 +88,7 @@ def test_busy_time_is_cut_to_the_traced_window():
 
 def test_readers_on_a_synthetic_context():
     cell = spec.load_cell(ROOT, "mixtral.single-decode")
-    model = spec.model_config(cell.config)
+    model = cell.model
     req = harness.Served(0, np.zeros(200, np.int32), 8)
     req.times = [960, 990]                        # first token after span
     req.tokens = [1, 2]
@@ -111,7 +111,7 @@ def test_readers_on_a_synthetic_context():
 def test_a_reader_that_finds_nothing_returns_none():
     r = trace.Reduction(a=0, b=1000, ops=[], modules=[], spans=[])
     cell = spec.load_cell(ROOT, "mixtral.single-decode")
-    ctx = trace.Context(cell=cell, model=spec.model_config(cell.config),
+    ctx = trace.Context(cell=cell, model=cell.model,
                         served=[], t0=0, t1=1000, stats0=None, stats1=None,
                         peak=spec.peaks(ROOT, "TPU v5 lite"), trace=r)
     for m in ("decode_step_ms", "device_idle", "decode_mfu",

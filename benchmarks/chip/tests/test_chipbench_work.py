@@ -12,7 +12,7 @@ CHIP = Path(__file__).resolve().parents[1]
 @pytest.fixture(scope="module")
 def mixtral():
     conf = json.loads((CHIP / "configs/mixtral-8x7b-l3.json").read_text())
-    return spec.model_config(conf)
+    return spec.model_config(CHIP.parents[1], conf)
 
 
 def test_dense_flops_per_token_layer(mixtral):

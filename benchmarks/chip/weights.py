@@ -2,10 +2,16 @@
 
 The tree (names, shapes, dtypes) is the one ``init_params`` would build,
 read with ``jax.eval_shape`` so nothing is allocated for it. Every leaf is
-then drawn inside one jitted program from the run's seed: norms are ones,
+then drawn inside one jitted program from the run's seed, by its role:
 embeddings and the LM head are N(0, 1/d_model), every other matrix is
 N(0, 1/fan_in) with the fan-in on its second-to-last axis — the scales
-``init_params`` uses. Tables with a leading layer or expert axis are drawn
+``init_params`` uses. A leaf's role is read from its last path
+component, whatever its leading layer or expert axes: a norm scale
+(``ln*``, ``final_norm``, ``scale``) is drawn as ones, a bias (``BIASES``)
+N(0, 0.02²): the ``initializer_range`` of Hugging Face configurations,
+not zero, so a program that drops a bias moves the logits. Any other
+leaf that is a vector (under ``scan/`` once its layer axis is set aside)
+is refused. Tables with a leading layer or expert axis are drawn
 one [fan_in, fan_out] slice at a time (``lax.map``), so no float32 copy of
 a whole expert table is ever live: set-up stays under serving's own
 high-water mark of HBM.
@@ -35,10 +41,21 @@ def _leaf_name(path) -> str:
     return "/".join(str(getattr(k, "key", k)) for k in path)
 
 
+BIAS_STD = 0.02
+BIASES = ("bq", "bk", "bv", "bo", "bias")
+NORMS = ("final_norm", "scale")
+
+
 def _draw(key, shape, dtype, name: str):
     last = name.rsplit("/", 1)[-1]
-    if last.startswith("ln") or last == "final_norm":
+    if last.startswith("ln") or last in NORMS:
         return jnp.ones(shape, dtype)
+    if last in BIASES:
+        return (jax.random.normal(key, shape, jnp.float32) * BIAS_STD
+                ).astype(dtype)
+    if len(shape) == 1 + name.startswith("scan/"):
+        raise ValueError(f"weight leaf {name} {tuple(shape)} is a vector "
+                         f"but neither a norm scale nor a bias")
     if last in ("embed", "lm_head"):
         scale = shape[-1] ** -0.5
     else:
