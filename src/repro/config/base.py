@@ -22,10 +22,32 @@ class MoEConfig:
     top_k: int
     d_ff: int                       # per-expert hidden size
     num_shared_experts: int = 0     # always-on shared experts (llama4-style)
+    # "softmax": top-k of the softmax, renormalised over the k picks
+    # (Mixtral); "sparsemixer": SparseMixer's inference routing
+    # (Phi-3.5-MoE, models/moe.py ``sparsemixer``), not renormalised
+    router: Literal["softmax", "sparsemixer"] = "softmax"
+    # the router's jitter eps (``router_jitter_noise``): SparseMixer masks
+    # logits farther than 2·eps, relatively, below each pick
     router_jitter: float = 0.0
     capacity_factor: float = 1.25   # train-time dispatch capacity (drops ok)
     serve_capacity_factor: float = 8.0  # prefill/serve: effectively dropless
     aux_loss_coef: float = 0.01     # load-balance loss weight
+
+
+@dataclass(frozen=True)
+class LongRoPE:
+    """LongRoPE rotary scaling as Phi-3.5 publishes it (``rope_scaling``
+    of type ``longrope``): each rotary frequency is divided by a
+    per-frequency factor and cos and sin are multiplied by an mscale —
+    the short factors and mscale up to
+    ``original_max_position_embeddings`` positions, the long ones past
+    it. The long regime re-rotates every position, cached keys too, which
+    a KV cache cannot do: the program holds the short regime alone and
+    refuses a capacity beyond it (``ModelConfig.check_capacity``)."""
+
+    short_factor: Tuple[float, ...]     # one per frequency: head_dim / 2
+    short_mscale: float
+    original_max_position_embeddings: int
 
 
 @dataclass(frozen=True)
@@ -72,10 +94,15 @@ class ModelConfig:
     window_pattern: Tuple[int, ...] = ()
     # Attention details ----------------------------------------------------
     qkv_bias: bool = False
+    attn_out_bias: bool = False      # a bias on the output projection (bo)
     rope_theta: float = 10000.0
+    rope_scaling: Optional[LongRoPE] = None
     mrope: bool = False              # multimodal 3D RoPE (qwen2-vl)
     logit_softcap: float = 0.0
     tie_embeddings: bool = False
+    lm_head_bias: bool = False       # a bias on the logits
+    # "rms": RMSNorm, a scale; "layer": LayerNorm, a scale and a bias
+    norm: Literal["rms", "layer"] = "rms"
     # Encoder-decoder -----------------------------------------------------
     encoder_layers: int = 0          # >0 -> enc-dec model (seamless)
     # Modality frontend stub: inputs are precomputed embeddings of this dim
@@ -90,6 +117,20 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
 
     # Derived -------------------------------------------------------------
+    def check_capacity(self, capacity: int) -> None:
+        """Raises where a KV capacity of ``capacity`` positions is one the
+        model's rotary embedding cannot serve from a cache: LongRoPE past
+        ``original_max_position_embeddings`` switches every position,
+        cached keys included, to the long factors."""
+        rs = self.rope_scaling
+        if rs is not None and capacity > rs.original_max_position_embeddings:
+            raise ValueError(
+                f"{self.name}: capacity {capacity} > LongRoPE's "
+                f"original_max_position_embeddings "
+                f"{rs.original_max_position_embeddings}: past it every "
+                f"position, cached keys too, turns to the long factors, "
+                f"and a KV cache cannot re-rotate the keys it holds")
+
     @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
@@ -274,6 +315,12 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
             cfg.ssm, d_state=32, head_dim=32, chunk_size=64)
     if cfg.window_pattern:
         changes["window_pattern"] = tuple(64 if w > 0 else -1 for w in cfg.window_pattern)
+    if cfg.rope_scaling is not None:
+        # one factor per frequency of the smaller head, evenly spaced
+        rs, half = cfg.rope_scaling, changes["head_dim"] // 2
+        step = len(rs.short_factor) // half
+        changes["rope_scaling"] = dataclasses.replace(
+            rs, short_factor=rs.short_factor[::step][:half])
     changes.update(overrides)
     return dataclasses.replace(cfg, **changes)
 

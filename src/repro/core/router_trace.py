@@ -145,9 +145,9 @@ def capture_trace(cfg, params, tokens, top_k: Optional[int] = None) -> np.ndarra
         for j, slot in enumerate(slots):
             lp = lp_group[f"s{j}"]
             if slot.is_moe:
-                h = transformer.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                h = transformer.norm(lp["ln2"], x, cfg)
                 _, top_i, _ = route(lp["moe"]["router"],
-                                    h[0].astype(jnp.float32), K)
+                                    h[0].astype(jnp.float32), K, cfg.moe)
                 picks.append(np.asarray(top_i))
             x, _, _, _ = transformer._apply_layer(lp, x, slot, cfg, positions,
                                                   "train", None, None)

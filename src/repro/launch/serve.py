@@ -74,6 +74,11 @@ def main() -> None:
                          "many chunks per tick between decode steps, so "
                          "established requests keep decoding while it "
                          "warms (0 = synchronous admission)")
+    ap.add_argument("--admit-slots-per-tick", type=int, default=0,
+                    help="overlapped admission: at most this many "
+                         "admitting slots advance per tick, the earliest "
+                         "admitted first, so a decode step waits behind "
+                         "at most this many slots' prefill (0 = all)")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="bound the scheduler queue; a full queue blocks "
                          "submit() (backpressure) instead of growing "
@@ -186,6 +191,7 @@ def main() -> None:
                          prefill_chunk=args.prefill_chunk,
                          prefill_segment=args.prefill_segment,
                          admit_chunks_per_tick=args.admit_chunks_per_tick,
+                         admit_slots_per_tick=args.admit_slots_per_tick,
                          prefetch=prefetch,
                          prefetch_min_prob=args.prefetch_min_prob,
                          prefetch_rank_votes=args.prefetch_rank_votes,

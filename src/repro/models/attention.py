@@ -33,7 +33,8 @@ NEG_INF = -1e30
 
 
 def attn_params(key, d_model: int, num_heads: int, num_kv_heads: int,
-                head_dim: int, qkv_bias: bool = False) -> Params:
+                head_dim: int, qkv_bias: bool = False,
+                out_bias: bool = False) -> Params:
     ks = jax.random.split(key, 4)
     p = {
         "wq": _dense_init(ks[0], (d_model, num_heads * head_dim)),
@@ -45,6 +46,8 @@ def attn_params(key, d_model: int, num_heads: int, num_kv_heads: int,
         p["bq"] = jnp.zeros((num_heads * head_dim,), jnp.bfloat16)
         p["bk"] = jnp.zeros((num_kv_heads * head_dim,), jnp.bfloat16)
         p["bv"] = jnp.zeros((num_kv_heads * head_dim,), jnp.bfloat16)
+    if out_bias:
+        p["bo"] = jnp.zeros((d_model,), jnp.bfloat16)
     return p
 
 
@@ -61,13 +64,21 @@ def _project_qkv(p: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array, ja
     return q, k, v
 
 
+def _out_proj(p: Params, o: jax.Array) -> jax.Array:
+    """The output projection, and its bias where the model has one."""
+    y = o @ p["wo"]
+    return y + p["bo"] if "bo" in p else y
+
+
 def _rope_qk(q, k, positions, cfg):
+    """Rotary embeddings of q and k (LongRoPE-scaled where the config
+    has ``rope_scaling``): the one helper every attention path calls."""
     if cfg.mrope:
         q = apply_mrope(q, positions, cfg.rope_theta)
         k = apply_mrope(k, positions, cfg.rope_theta)
     else:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k
 
 
@@ -218,7 +229,7 @@ def self_attention(p: Params, x: jax.Array, positions: jax.Array, cfg,
     k = constrain(k, ("pod", "data"), None, None, None)
     o = flash_attention(q, k, v, window=window, causal=causal)
     o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    return o @ p["wo"]
+    return _out_proj(p, o)
 
 
 # --------------------------------------------------------------------------
@@ -255,7 +266,7 @@ def segment_attention(p: Params, x: jax.Array, cache: Params, pos: jax.Array,
     o, _ = _flash_fwd_scan(q, k_att, v_cache, window, True, 1024,
                            q_offset=jnp.asarray(pos, jnp.int32))
     o = o.reshape(B, C, cfg.num_heads * cfg.head_dim)
-    return o @ p["wo"], {"k": k_cache, "v": v_cache}
+    return _out_proj(p, o), {"k": k_cache, "v": v_cache}
 
 
 def segment_attention_paged(p: Params, x: jax.Array, cache: Params,
@@ -331,7 +342,7 @@ def segment_attention_paged(p: Params, x: jax.Array, cache: Params,
         o, _ = _flash_fwd_scan(q, k_att, v_cache, window, True, 1024,
                                q_offset=jnp.asarray(pos, jnp.int32))
     o = o.reshape(B, C, cfg.num_heads * hd)
-    return o @ p["wo"], {"k": k_pool, "v": v_pool}
+    return _out_proj(p, o), {"k": k_pool, "v": v_pool}
 
 
 # --------------------------------------------------------------------------
@@ -387,7 +398,7 @@ def decode_attention(p: Params, x: jax.Array, cache: Params, pos: jax.Array,
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bkhd->bhgd", w, v_cache.astype(jnp.float32))
     o = o.reshape(B, 1, cfg.num_heads * hd).astype(x.dtype)
-    return o @ p["wo"], {"k": k_cache, "v": v_cache}
+    return _out_proj(p, o), {"k": k_cache, "v": v_cache}
 
 
 def init_paged_kv_cache(num_pages: int, page_size: int, num_kv_heads: int,
@@ -464,7 +475,7 @@ def decode_attention_paged(p: Params, x: jax.Array, cache: Params,
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bkhd->bhgd", w, v_cache.astype(jnp.float32))
     o = o.reshape(B, 1, cfg.num_heads * hd).astype(x.dtype)
-    return o @ p["wo"], {"k": k_pool, "v": v_pool}
+    return _out_proj(p, o), {"k": k_pool, "v": v_pool}
 
 
 # --------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Shared neural-net layers: RMSNorm, RoPE/M-RoPE, SwiGLU, embeddings.
+"""Shared neural-net layers: RMSNorm, LayerNorm, RoPE/M-RoPE (LongRoPE
+scaling), SwiGLU, embeddings.
 
 All functions are pure; parameters are plain dict pytrees created by the
 matching ``*_params`` initializer. Compute dtype is bf16, normalization and
@@ -34,20 +35,61 @@ def rmsnorm(w: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# RoPE (standard + multimodal 3-D M-RoPE)
+# LayerNorm (a scale and a bias) and the norm of a config
 # --------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def layernorm(p: Params, x: jax.Array, eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+            ).astype(x.dtype)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, hd]; positions: broadcastable to [..., S] (int)."""
+def norm_params(d: int, kind: str):
+    """A norm's weights: RMSNorm's scale, or LayerNorm's scale and bias
+    (``ModelConfig.norm``)."""
+    if kind == "layer":
+        return {"scale": jnp.ones((d,), jnp.float32),
+                "bias": jnp.zeros((d,), jnp.float32)}
+    return rmsnorm_params(d)
+
+
+def norm(p, x: jax.Array, cfg) -> jax.Array:
+    """The config's norm (``cfg.norm``, eps ``cfg.norm_eps``): the one
+    helper the backbone and the serving engine's decode step both call."""
+    if cfg.norm == "layer":
+        return layernorm(p, x, cfg.norm_eps)
+    return rmsnorm(p, x, cfg.norm_eps)
+
+
+# --------------------------------------------------------------------------
+# RoPE (standard + LongRoPE scaling + multimodal 3-D M-RoPE)
+# --------------------------------------------------------------------------
+
+def rope_freqs(head_dim: int, theta: float, factors=None) -> jax.Array:
+    """Inverse frequencies [hd/2]; LongRoPE's per-frequency ``factors``
+    divide them."""
+    base = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if factors is not None:
+        base = jnp.asarray(factors, jnp.float32) * base
+    return 1.0 / base
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: [..., S, H, hd]; positions: broadcastable to [..., S] (int).
+    ``scaling`` (a ``LongRoPE``) divides each frequency by its short
+    factor and multiplies cos and sin by the short mscale: the regime of
+    every capacity the program serves (``ModelConfig.check_capacity``)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                                  # [hd/2]
+    factors = None if scaling is None else scaling.short_factor
+    freqs = rope_freqs(hd, theta, factors)                         # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs      # [..., S, hd/2]
     cos = jnp.cos(angles)[..., None, :]                            # [..., S, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        cos, sin = cos * scaling.short_mscale, sin * scaling.short_mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

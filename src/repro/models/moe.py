@@ -43,14 +43,49 @@ def moe_params(key, d_model: int, m: MoEConfig) -> Params:
     return p
 
 
-def route(router_w: jax.Array, x: jax.Array, top_k: int
+def route(router_w: jax.Array, x: jax.Array, top_k: int,
+          m: Optional[MoEConfig] = None
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x: [T, D] -> (probs [T, E] fp32, top-k ids [T, K], top-k weights [T, K])."""
+    """x: [T, D] -> (probs [T, E] fp32, top-k ids [T, K], top-k weights [T, K]).
+
+    By ``m.router``: "softmax" (the default, also without ``m``) takes the
+    top-k of the softmax and renormalises them over the k picks;
+    "sparsemixer" picks and weighs as :func:`sparsemixer` with eps
+    ``m.router_jitter``. ``probs`` is the softmax either way."""
     logits = x.astype(jnp.float32) @ router_w
     probs = jax.nn.softmax(logits, axis=-1)
+    if m is not None and m.router == "sparsemixer":
+        top_i, top_w = sparsemixer(logits, top_k, m.router_jitter)
+        return probs, top_i, top_w
     top_w, top_i = jax.lax.top_k(probs, top_k)
     top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)  # renormalize
     return probs, top_i, top_w
+
+
+def sparsemixer(logits: jax.Array, top_k: int, eps: float
+                ) -> Tuple[jax.Array, jax.Array]:
+    """SparseMixer's inference routing (arXiv:2310.00811), as
+    Phi-3.5-MoE's published code runs it:
+    logits [T, E] -> (ids [T, K] int32, weights [T, K] fp32).
+
+    Pick k is the argmax of the logits with picks 1..k-1 set to -inf.
+    Every logit s whose distance below that pick's logit m,
+    ``(m - s) / max(|s|, m)``, exceeds ``2 * eps`` is masked to -inf, and
+    the pick's weight is the softmax of what is left, at the pick. The k
+    weights are not renormalised."""
+    E = logits.shape[-1]
+    masked = logits
+    ids, weights = [], []
+    for _ in range(top_k):
+        top = masked.max(-1, keepdims=True)
+        pick = jnp.argmax(masked, -1)
+        far = (top - logits) / jnp.maximum(jnp.abs(logits), top) > 2 * eps
+        gates = jax.nn.softmax(jnp.where(far, -jnp.inf, masked), -1)
+        ids.append(pick)
+        weights.append(jnp.take_along_axis(gates, pick[:, None], -1)[:, 0])
+        masked = jnp.where(jax.nn.one_hot(pick, E, dtype=bool), -jnp.inf,
+                           masked)
+    return jnp.stack(ids, -1).astype(jnp.int32), jnp.stack(weights, -1)
 
 
 def load_balance_loss(probs: jax.Array, top_i: jax.Array, num_experts: int) -> jax.Array:
@@ -105,7 +140,7 @@ def moe_apply(p: Params, x: jax.Array, m: MoEConfig,
         cf = m.capacity_factor
 
     xf = x.reshape(B * S, D)
-    probs, top_i, top_w = route(p["router"], xf, K)
+    probs, top_i, top_w = route(p["router"], xf, K, m)
     aux = load_balance_loss(probs, top_i, E)
 
     if S == 1:

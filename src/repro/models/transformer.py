@@ -33,7 +33,7 @@ from repro.sharding.ctx import constrain_sp
 from . import attention as attn
 from . import ssm
 from .layers import embed_lookup, embed_params, ffn_apply, ffn_params, \
-    logits_from_embed, rmsnorm, rmsnorm_params, _dense_init
+    logits_from_embed, norm, norm_params, _dense_init
 from .moe import moe_apply, moe_params, route
 
 Params = Dict[str, Any]
@@ -69,14 +69,15 @@ def _slot_has_ffn(cfg: ModelConfig, slot: Slot) -> bool:
 
 def _layer_params(key, cfg: ModelConfig, slot: Slot) -> Params:
     ks = jax.random.split(key, 4)
-    p: Params = {"ln1": rmsnorm_params(cfg.d_model)}
+    p: Params = {"ln1": norm_params(cfg.d_model, cfg.norm)}
     if slot.kind == "attn":
         p["attn"] = attn.attn_params(ks[0], cfg.d_model, cfg.num_heads,
-                                     cfg.num_kv_heads, cfg.head_dim, cfg.qkv_bias)
+                                     cfg.num_kv_heads, cfg.head_dim,
+                                     cfg.qkv_bias, cfg.attn_out_bias)
     else:
         p["mamba"] = ssm.mamba_params(ks[0], cfg)
     if _slot_has_ffn(cfg, slot):
-        p["ln2"] = rmsnorm_params(cfg.d_model)
+        p["ln2"] = norm_params(cfg.d_model, cfg.norm)
         if slot.is_moe:
             p["moe"] = moe_params(ks[1], cfg.d_model, cfg.moe)
         else:
@@ -88,9 +89,11 @@ def init_params(cfg: ModelConfig, key) -> Params:
     slots, G, R = build_slots(cfg)
     keys = jax.random.split(key, 4 + len(slots) * (G + 1))
     params: Params = {"embed": embed_params(keys[0], cfg.vocab_size, cfg.d_model),
-                      "final_norm": rmsnorm_params(cfg.d_model)}
+                      "final_norm": norm_params(cfg.d_model, cfg.norm)}
     if not cfg.tie_embeddings:
         params["lm_head"] = embed_params(keys[1], cfg.vocab_size, cfg.d_model)
+    if cfg.lm_head_bias:
+        params["logits"] = {"bias": jnp.zeros((cfg.vocab_size,), jnp.bfloat16)}
     if cfg.frontend_embed_dim:
         params["frontend_proj"] = _dense_init(
             keys[2], (cfg.frontend_embed_dim, cfg.d_model))
@@ -122,13 +125,16 @@ def _layer_state(cfg: ModelConfig, slot: Slot, batch: int, capacity: int) -> Par
 
 
 def init_state(cfg: ModelConfig, batch: int, capacity: int) -> Params:
-    """Decode state pytree, mirroring the scan/rem param structure."""
+    """Decode state pytree, mirroring the scan/rem param structure. Every
+    state starts at zero; each stacked leaf is allocated once, at its
+    final shape, so building the state holds no second copy of it."""
     slots, G, R = build_slots(cfg)
     state: Params = {"scan": {}, "pos": jnp.zeros((), jnp.int32)}
     for j, slot in enumerate(slots):
-        st = _layer_state(cfg, slot, batch, capacity)
+        st = jax.eval_shape(
+            lambda slot=slot: _layer_state(cfg, slot, batch, capacity))
         state["scan"][f"s{j}"] = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (G,) + x.shape).copy(), st)
+            lambda s: jnp.zeros((G,) + s.shape, s.dtype), st)
     if R:
         state["rem"] = {f"r{j}": _layer_state(cfg, slots[j % len(slots)], batch, capacity)
                         for j in range(R)}
@@ -161,7 +167,7 @@ def _apply_layer(lp: Params, x: jax.Array, slot: Slot, cfg: ModelConfig,
     [B, max_pages] table; ``kv_write_min``/``kv_write_max`` bound which
     absolute positions may land — shared prefix pages stay immutable)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+    h = norm(lp["ln1"], x, cfg)
     new_state = None
     if slot.kind == "attn":
         if mode == "decode":
@@ -197,7 +203,7 @@ def _apply_layer(lp: Params, x: jax.Array, slot: Slot, cfg: ModelConfig,
     x = x + o
     trace = None
     if _slot_has_ffn(cfg, slot):
-        h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+        h2 = norm(lp["ln2"], x, cfg)
         if slot.is_moe:
             # train drops at capacity (standard); serving must not — a
             # prefill-dropped token would diverge from the decode path
@@ -207,7 +213,7 @@ def _apply_layer(lp: Params, x: jax.Array, slot: Slot, cfg: ModelConfig,
                 B, S, _ = h2.shape
                 K = cfg.moe.top_k
                 _, top_i, top_w = route(lp["moe"]["router"],
-                                        h2.reshape(B * S, -1), K)
+                                        h2.reshape(B * S, -1), K, cfg.moe)
                 trace = {"top_i": top_i.reshape(B, S, K),
                          "top_w": top_w.reshape(B, S, K), "h2": h2}
         else:
@@ -337,7 +343,7 @@ def backbone(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
             rem_traces[f"r{j}"] = tr
         aux = aux + a
 
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    x = norm(params["final_norm"], x, cfg)
 
     new_state = None
     if mode in ("prefill", "decode", "segment"):
@@ -359,5 +365,8 @@ def backbone(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
 
 
 def lm_logits(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Logits of the final-normed hidden states, with the LM head's bias
+    where the model has one (``params["logits"]["bias"]``)."""
     table = params.get("lm_head", params["embed"])
-    return logits_from_embed(table, x, cfg.logit_softcap)
+    lg = logits_from_embed(table, x, cfg.logit_softcap)
+    return lg + params["logits"]["bias"] if "logits" in params else lg

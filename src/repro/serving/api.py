@@ -15,7 +15,6 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 
 from repro.config import CacheConfig, ModelConfig, get_config, reduced
-from repro.models import init_params
 
 from .engine import CollaborativeEngine, EngineConfig
 from .scheduler import ContinuousBatchingScheduler
@@ -46,7 +45,8 @@ def build(arch: Union[str, ModelConfig], *,
               ``prefill_chunk`` / ``admit_chunks_per_tick``).
     seed    — seeds parameter init, static cache placement and the
               scheduler's fallback sampling chains.
-    params  — pre-initialized parameters (skips ``init_params``).
+    params  — pre-initialized parameters; without them the engine draws
+              them (``init_params``) once it has accepted the config.
     reduce  — apply :func:`repro.config.reduced` (the CPU-container
               geometry) to arch-id lookups; pass False to serve the full
               config.
@@ -85,8 +85,6 @@ def build(arch: Union[str, ModelConfig], *,
         ecfg = EngineConfig(cache=ccfg, **(serving or {}))
 
     key = jax.random.PRNGKey(seed)
-    if params is None:
-        params = init_params(cfg, key)
     engine = CollaborativeEngine(cfg, params, ecfg, key=key,
                                  recorder=recorder)
     scheduler = ContinuousBatchingScheduler(

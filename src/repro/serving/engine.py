@@ -84,7 +84,7 @@ from repro.core import collaborative as collab
 from repro.obs.trace import NULL_RECORDER, now_ns
 from repro.models import transformer
 from repro.models import attention as attn
-from repro.models.layers import rmsnorm
+from repro.models.layers import norm
 from repro.models.moe import route
 from .kv_pool import KVPagePool, PageTable
 from .sampling import GREEDY, SamplingParams, batch_arrays, fold_keys, \
@@ -115,6 +115,11 @@ class EngineConfig:
     # newcomer warms. 0 = synchronous admission (the whole replay runs on
     # the admission tick — head-of-line blocking on long prompts).
     admit_chunks_per_tick: int = 0
+    # overlapped admission across slots: at most this many PREFILLING
+    # slots advance on one tick, the earliest admitted first, so a decode
+    # step never waits behind more than this many slots' prefill work
+    # (0 = every PREFILLING slot advances every tick)
+    admit_slots_per_tick: int = 0
     # segment-streamed prefill: forward the prompt in this-many-token
     # segments, one advance_prefill call each, instead of one full-prompt
     # forward on the admission tick (0 = one-shot). Each segment appends
@@ -156,6 +161,10 @@ class EngineConfig:
             raise ValueError(
                 f"admit_chunks_per_tick must be >= 0, got "
                 f"{self.admit_chunks_per_tick}")
+        if self.admit_slots_per_tick < 0:
+            raise ValueError(
+                f"admit_slots_per_tick must be >= 0, got "
+                f"{self.admit_slots_per_tick}")
         if self.prefill_segment < 0:
             raise ValueError(
                 f"prefill_segment must be >= 0, got {self.prefill_segment}")
@@ -275,11 +284,15 @@ class CollaborativeEngine:
     path without the cache lives in launch/serve.py for all archs.
     """
 
-    def __init__(self, cfg: ModelConfig, params: Params, ecfg: EngineConfig,
-                 key=None, recorder=None):
+    def __init__(self, cfg: ModelConfig, params: Optional[Params],
+                 ecfg: EngineConfig, key=None, recorder=None):
         assert cfg.moe is not None and cfg.moe_every == 1 and not cfg.is_encdec
         slots, G, R = transformer.build_slots(cfg)
         assert len(slots) == 1 and R == 0, "engine expects homogeneous stacks"
+        cfg.check_capacity(ecfg.capacity)    # before any weight is made
+        key = key if key is not None else jax.random.PRNGKey(0)
+        if params is None:
+            params = transformer.init_params(cfg, key)
         self.cfg, self.ecfg = cfg, ecfg
         self.params = params
         # trace recorder (repro.obs): the no-op twin when tracing is off,
@@ -290,7 +303,6 @@ class CollaborativeEngine:
         # last-seen cumulative pool/executor counters, so the drain
         # helpers can emit per-step deltas as instants
         self._obs_prev: Dict[str, int] = {}
-        key = key if key is not None else jax.random.PRNGKey(0)
 
         # Split expert weights out of the param tree into the two tiers.
         # The host tier is read-only and IS the param tree's expert table
@@ -361,7 +373,8 @@ class CollaborativeEngine:
             "prefill_tokens": 0, "prefill_chunks": 0, "first_tokens": 0,
             "prefill_segments": 0, "prefix_tokens_skipped": 0,
             "cpu_expert_calls": 0, "cpu_tokens": 0, "miss_expert_groups": 0,
-            "fused_groups": 0, "kv_pages_in_use": 0, "prefix_hits": 0,
+            "fused_groups": 0, "unique_experts": 0,
+            "kv_pages_in_use": 0, "prefix_hits": 0,
             "cow_forks": 0, "prefix_pages_retained": 0}
         self._per_layer_hits = np.zeros(L, np.int64)
         self._per_layer_accesses = np.zeros(L, np.int64)
@@ -446,7 +459,8 @@ class CollaborativeEngine:
             # weight-table duplicates.
             with jax.named_scope("moe_prefetch"):
                 xs.update(
-                    ln2_next=jnp.roll(scan_p["ln2"], -1, axis=0),
+                    ln2_next=jax.tree.map(lambda a: jnp.roll(a, -1, axis=0),
+                                          scan_p["ln2"]),
                     router_next=jnp.roll(scan_p["moe"]["router"], -1,
                                          axis=0),
                     has_next=jnp.arange(cfg.num_layers) < cfg.num_layers - 1)
@@ -455,7 +469,7 @@ class CollaborativeEngine:
             x, tiers, layer, pred_prev, rep_prev, issued_prev = carry
             lp, st = xs["params"], xs["state"]
             with jax.named_scope("attn"):
-                h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+                h = norm(lp["ln1"], x, cfg)
                 if self.ecfg.kv_paged:
                     o, new_st = attn.decode_attention_paged(
                         lp["attn"], h, st, pos, pages, cfg, slot.window,
@@ -465,9 +479,10 @@ class CollaborativeEngine:
                         lp["attn"], h, st, pos, cfg, slot.window)
                 x = x + o
             with jax.named_scope("router"):
-                h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                h2 = norm(lp["ln2"], x, cfg)
                 _, top_i, top_w = route(lp["moe"]["router"],
-                                        h2[:, 0].astype(jnp.float32), K)
+                                        h2[:, 0].astype(jnp.float32), K,
+                                        cfg.moe)
 
             # staged collaborative MoE: probe -> dispatch/execute -> commit
             pr = collab.probe(tiers, layer, top_i, ccfg, active=active)
@@ -509,10 +524,10 @@ class CollaborativeEngine:
                     # later) — the DAOP-style one-layer lookahead; the
                     # reservation's transfer hides under layer l+1's
                     # attention
-                    h_pred = rmsnorm(xs["ln2_next"], x, cfg.norm_eps)
+                    h_pred = norm(xs["ln2_next"], x, cfg)
                     pred_p, pred_i, _ = route(
                         xs["router_next"], h_pred[:, 0].astype(jnp.float32),
-                        K)
+                        K, cfg.moe)
                     gate = xs["has_next"] & active[:, None]
                     if self.ecfg.prefetch_min_prob > 0.0:
                         # confidence gate: only reserve picks whose router
@@ -537,6 +552,8 @@ class CollaborativeEngine:
             stats = {
                 **collab._stats(pr, fetch),
                 **dstats,
+                # distinct experts the layer computed this step
+                "unique_experts": (pr.rep_e >= 0).sum(),
                 "prefetch_issued": n_issued,
                 "prefetch_wasted": wasted,
                 "predicted": predicted,
@@ -551,7 +568,7 @@ class CollaborativeEngine:
         (x, tiers, _, _, _, _), (new_scan, stats) = jax.lax.scan(
             body, carry0, xs)
         with jax.named_scope("lm_head"):
-            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            x = norm(params["final_norm"], x, cfg)
             logits = transformer.lm_logits(params, x, cfg)
         new_state = {"scan": {"s0": new_scan},
                      "pos": pos + active.astype(jnp.int32)}
@@ -1244,8 +1261,14 @@ class CollaborativeEngine:
         for k in ("hits", "accesses", "fetched_experts", "prefetch_issued",
                   "prefetch_hits", "prefetch_wasted", "predicted",
                   "predicted_correct", "cpu_expert_calls", "cpu_tokens",
-                  "miss_expert_groups", "fused_groups"):
+                  "miss_expert_groups"):
             c[k] += int(np.asarray(stats[k]).sum())
+        # the distinct-expert count rides fused_groups' transfer rather
+        # than adding a device->host round trip of its own
+        fused, unique = jax.device_get((stats["fused_groups"],
+                                        stats["unique_experts"]))
+        c["fused_groups"] += int(np.asarray(fused).sum())
+        c["unique_experts"] += int(np.asarray(unique).sum())
         c["host_assignments"] += int(
             np.asarray(stats["host_flops_assignments"]).sum())
         # scan stacks one entry per layer: accumulate the per-layer series

@@ -21,7 +21,9 @@ shared expert cache:
                   ``admit_chunks_per_tick``) prompt segments through the
                   backbone — forward, KV append and cache warm fused —
                   with the first token sampled on the tick whose segment
-                  completes the prompt.
+                  completes the prompt. ``EngineConfig.
+                  admit_slots_per_tick`` caps how many PREFILLING slots
+                  advance on one tick, the earliest admitted first.
   * decode tick — every step decodes the whole padded slot batch in one
                   jitted call; each slot sits at its own KV position
                   (per-slot ``pos`` vector) and inactive or PREFILLING
@@ -481,16 +483,25 @@ class ContinuousBatchingScheduler:
         stream): the whole ticket at once when
         ``admit_chunks_per_tick == 0`` (synchronous admission), at most
         that many chunks/segments otherwise — the overlapped path that
-        keeps decode ticks flowing under a long-prompt admission. A
+        keeps decode ticks flowing under a long-prompt admission. With
+        ``admit_slots_per_tick`` only that many slots advance, the
+        earliest admitted first; the others wait a tick. A
         drained ticket flips its slot into the decode set of THIS tick
         (matching the synchronous path's admit-and-decode-same-tick
         behaviour). A drained segment-streamed ticket additionally owes
         the request its deferred first token: sampled, bound and
         streamed here."""
         per_tick = self.engine.ecfg.admit_chunks_per_tick
-        for t, ticket in enumerate(self._tickets):
+        order = [t for t, ticket in enumerate(self._tickets)
+                 if ticket is not None and self.slots[t] is not None]
+        n_slots = self.engine.ecfg.admit_slots_per_tick
+        if n_slots:
+            order = sorted(order, key=lambda t: self.slots[t].t_admit)
+            order = order[:n_slots]
+        for t in order:
+            ticket = self._tickets[t]
             if ticket is None or self.slots[t] is None:
-                continue
+                continue    # cancelled by an earlier slot's callback
             budget = ticket.remaining if per_tick == 0 \
                 else min(per_tick, ticket.remaining)
             self.state, done = self.engine.advance_prefill_state(

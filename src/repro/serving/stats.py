@@ -71,6 +71,9 @@ class EngineStats:
     # CPU-miss groups the host executor's small-group fusion lane batched
     # into one stacked matmul instead of one pool task each
     fused_groups: int = 0
+    # distinct experts the decode steps computed, summed over layers and
+    # steps (a step's mean per layer: unique_experts / (steps * layers))
+    unique_experts: int = 0
     # executor pool-census channel (best-effort floors — the pure_callback
     # lane may re-invoke): censused dispatches, their summed effective
     # worker counts (mean workers = census_threads / census_calls), and

@@ -43,8 +43,8 @@ ENGINE_KEYS = {
     "prefill_fetched", "prefill_tokens", "prefill_chunks", "first_tokens",
     "prefill_segments", "prefix_tokens_skipped", "generated_tokens",
     "cpu_expert_calls", "cpu_tokens", "miss_expert_groups",
-    "fused_groups", "census_calls", "census_threads", "affinity_hits",
-    "host_busy_us", "host_queue_peak",
+    "fused_groups", "unique_experts", "census_calls", "census_threads",
+    "affinity_hits", "host_busy_us", "host_queue_peak",
     "kv_pages_in_use", "prefix_hits", "cow_forks",
     "prefix_pages_retained",
     "hit_rate", "prefetch_hit_rate", "prefetch_waste_rate",

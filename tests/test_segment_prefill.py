@@ -167,6 +167,39 @@ def test_prefix_hit_segmented_admission_parity(setup):
 
 # ---------------------------------------------------------------------------
 # deferred-first-token guards
+def test_admit_slots_per_tick_streams_one_slot_at_a_time(setup):
+    """With ``admit_slots_per_tick=1`` no tick advances more than one
+    PREFILLING slot, the earliest admitted first (slot 0 drains before
+    slot 1 starts), and the served tokens are the unlimited fleet's."""
+    cfg, params = setup
+    prompts = _fleet(cfg)
+    _, base = _serve(cfg, params, prompts, prefill_segment=4,
+                     admit_chunks_per_tick=1)
+    eng = _engine(cfg, params, slots=3, capacity=32, prefill_segment=4,
+                  admit_chunks_per_tick=1, admit_slots_per_tick=1)
+    sched = ContinuousBatchingScheduler(eng)
+    reqs = [sched.submit(p, max_new_tokens=1 if i == 0 else 6)
+            for i, p in enumerate(prompts)]
+    first_tick = {}
+    tick = 0
+    while sched.queue or any(s is not None for s in sched.slots):
+        before = eng.stats.prefill_segments
+        sched.step()
+        tick += 1
+        assert eng.stats.prefill_segments - before <= 1
+        for r in reqs:
+            if r.generated and r.rid not in first_tick:
+                first_tick[r.rid] = tick
+    for i, r in enumerate(reqs):
+        np.testing.assert_array_equal(r.generated, base[i])
+    # request 0 (slot 0) streams its segments before request 1 (slot 1)
+    segs = -(-len(prompts[0]) // 4)
+    assert first_tick[reqs[0].rid] == segs
+    assert first_tick[reqs[1].rid] == segs + -(-len(prompts[1]) // 4)
+    with pytest.raises(ValueError, match="admit_slots_per_tick"):
+        EngineConfig(cache=eng.ecfg.cache, admit_slots_per_tick=-1)
+
+
 # ---------------------------------------------------------------------------
 
 def test_streamed_ticket_guards_before_drain(setup):

@@ -5,7 +5,8 @@ dims are neither (8, 128)-divisible nor whole, unprovable alignment, VMEM
 budget), so every other kernel test passes on kernels the chip would
 refuse. Here each kernel is compiled at Mixtral-8x7B widths (D=4096,
 F=14336, H=32, Hk=8, hd=128, page 16) for a v5e chip that is described,
-not attached. The kernel functions are called with ``interpret=False``
+not attached, and the decode step of ``phi35.batch-decode`` whole, at
+Phi-3.5-MoE's published widths. The kernel functions are called with ``interpret=False``
 directly: the ops.py wrappers see the CPU backend here.
 
 The topology is described inside a module-scoped fixture, never while a
@@ -90,12 +91,18 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def _decode_step_text(one_chip, monkeypatch, max_batch=None,
-                      num_experts=None) -> str:
-    """The compiled text of the engine's decode step at reduced widths, for
-    the chip with its Pallas kernels: the ``mixtral.single-decode`` cell's
-    engine with prefetch on, optionally at another slot count and expert
-    count."""
+# one v5e chip's HBM, and the share of it one cell's decode step may
+# hold (its arguments, its scratch and its outputs): the rest is the
+# runtime's own
+HBM_BYTES = 16 * 2 ** 30
+HBM_SHARE = 0.95
+
+
+def _decode_step_compiled(one_chip, monkeypatch, workload, published):
+    """The engine's decode step compiled for the chip with its Pallas
+    kernels: the ``workload`` cell's engine with prefetch on, at the
+    cell's own widths and depth (``published``), or Mixtral's at reduced
+    widths."""
     import dataclasses
     from pathlib import Path
 
@@ -108,20 +115,21 @@ def _decode_step_text(one_chip, monkeypatch, max_batch=None,
     for mod in (attn_ops, gmm_ops, prefill_ops):
         monkeypatch.setattr(mod, "interpret_mode", lambda: False)
     jax.clear_caches()          # no interpret-mode trace of moe_ffn reused
-    cell = spec.load_cell(Path(__file__).resolve().parents[1],
-                          "mixtral.single-decode")
+    cell = spec.load_cell(Path(__file__).resolve().parents[1], workload)
     engine = dict(cell.config["engine"], prefetch=True)
-    model = reduced(get_config("mixtral-8x7b"))
-    if max_batch is not None:
-        engine["max_batch"] = max_batch
-    if num_experts is not None:
-        model = dataclasses.replace(model, moe=dataclasses.replace(
-            model.moe, num_experts=num_experts))
+    model = cell.model if published else reduced(get_config("mixtral-8x7b"))
     cell = dataclasses.replace(cell, config={**cell.config,
                                              "engine": engine})
-    text = scopes.decode_lowered(model, cell, one_chip).compile().as_text()
+    compiled = scopes.decode_lowered(model, cell, one_chip).compile()
     jax.clear_caches()
-    return text
+    return compiled
+
+
+def _decode_step_text(one_chip, monkeypatch,
+                      workload="mixtral.single-decode",
+                      published=False) -> str:
+    return _decode_step_compiled(one_chip, monkeypatch, workload,
+                                 published).as_text()
 
 
 def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
@@ -143,19 +151,29 @@ def test_decode_step_names_its_stages_for_v5e(one_chip, monkeypatch):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("max_batch,num_experts", [(None, None), (8, 16)],
-                         ids=["cell", "batch8_experts16"])
+@pytest.mark.parametrize("workload,published", [
+    ("mixtral.single-decode", False), ("phi35.batch-decode", True)],
+    ids=["cell", "batch8_experts16"])
 def test_decode_step_moves_expert_weights_as_slices_for_v5e(
-        one_chip, monkeypatch, max_batch, num_experts):
+        one_chip, monkeypatch, workload, published):
     """The expert cache moves weights as whole contiguous slices: no gather
     or scatter of floating-point values (expert weights) resolves to
-    ``moe_gather``, ``moe_commit`` or ``moe_prefetch``, at the cell's G = 2
-    groups and at 8 slots over 16 experts (G = 16). The cache's integer
-    bookkeeping (tags, ways, votes) may still gather and scatter; the
-    stage scopes are all still found."""
+    ``moe_gather``, ``moe_commit`` or ``moe_prefetch``, at Mixtral's G = 2
+    groups and at ``phi35.batch-decode``'s 8 slots over 16 experts (G =
+    16), the latter at Phi-3.5-MoE's published widths and the cell's 4
+    layers, where the step must also fit the chip's memory. The cache's
+    integer bookkeeping (tags, ways, votes) may still gather and scatter;
+    the stage scopes are all still found."""
     from benchmarks.chip import scopes
 
-    text = _decode_step_text(one_chip, monkeypatch, max_batch, num_experts)
+    compiled = _decode_step_compiled(one_chip, monkeypatch, workload,
+                                     published)
+    text = compiled.as_text()
+    if published:
+        m = compiled.memory_analysis()
+        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert held <= HBM_SHARE * HBM_BYTES, held
     dtype = dict(re.findall(
         r"^\s+(?:ROOT )?%(\S+) = \(?([a-z]+[0-9]*)\[", text, re.M))
     table = scopes.parse_hlo(text)
